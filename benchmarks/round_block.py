@@ -9,19 +9,20 @@ over P x R x capacity and records, per configuration:
     argsort band compaction). HLO flops / bytes accessed / collective bytes
     come from ``repro.launch.hlo_stats.collect_hlo_costs``.
   * **fused leg**: the same program with the Pallas kernels in the hot
-    path. Interpret-mode Pallas compiles to the *interpreter's* HLO (and on
-    TPU the kernels are opaque custom-calls), so the leg is split: the XLA
-    glue is compiled with every ``pl.pallas_call`` swapped for a
-    dependency-keeping stub (reduce inputs, broadcast into the outputs — a
-    zeros stub would let XLA dead-code the surrounding program), and each
-    kernel's HBM traffic is added from the kernel modules' analytic
-    ``*_traffic_bytes`` models — the same models the dispatch autotuner
-    scores candidates with.
+    path. Only the per-provider census is a kernel (``histogram.py``): the
+    grant/band gathers and the band compaction are XLA on every backend,
+    so they count as glue. Interpret-mode Pallas compiles to the
+    *interpreter's* HLO (and on TPU the kernels are opaque custom-calls),
+    so the leg is split: the XLA glue is compiled with every
+    ``pl.pallas_call`` swapped for a dependency-keeping stub (reduce
+    inputs, broadcast into the outputs — a zeros stub would let XLA
+    dead-code the surrounding program), and the kernel's HBM traffic is
+    added from ``histogram_traffic_bytes``.
 
 The resulting ``BENCH_round_block.json`` is committed at the repo root as
-the perf baseline; scripts/collective_gate.py re-measures it and fails on
->1.25x per-round byte/flop regression, and on the fused path ever costing
-more bytes than the jnp path.
+the count baseline (HLO bytes from a CPU compile, not speed);
+scripts/collective_gate.py re-measures it and fails on a >1.25x per-round
+byte/flop regression.
 
 Usage (the committed baseline is recorded on the 8-device host mesh):
   XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
@@ -45,10 +46,6 @@ from repro import api
 from repro.api import GraphSpec
 from repro.core.pba import stream_block_capacity
 from repro.kernels import dispatch
-from repro.kernels.band_compact import _tile_plan, band_compact_traffic_bytes
-from repro.kernels.edge_resolve import (BLOCK, MAX_VMEM_ENTRIES, _chunk_plan,
-                                        chunked_traffic_bytes,
-                                        gather_traffic_bytes)
 from repro.kernels.histogram import histogram_traffic_bytes
 from repro.launch.bench import compile_sharded_stream_round
 from repro.launch.hlo_stats import collect_hlo_costs
@@ -65,10 +62,9 @@ SWEEP = (
 )
 VPP, K = 200, 3  # vertices/proc, edges/vertex — e_local = VPP * K
 
-#: pl.pallas_call sites one round program traces (grant gather, band
-#: gather, per-provider histogram, fused band compaction).
-EXPECTED_KERNELS = ("_gather_kernel", "_gather_kernel", "_hist_kernel",
-                    "_band_compact_kernel")
+#: pl.pallas_call sites one round program traces: the per-provider
+#: histogram, once per resident row.
+EXPECTED_KERNELS = ("_hist_kernel",)
 
 
 def _round_spec(procs: int, rounds: int, pair_capacity: int) -> GraphSpec:
@@ -117,31 +113,12 @@ def _stub_pallas_calls(calls: list):
         pl.pallas_call = real  # spmdlint: disable=RPR007 — restore
 
 
-def _gather_bytes(m: int, n: int) -> float:
-    """Analytic traffic of one ops.gather at source length m — resident or
-    autotuned-chunked, mirroring the dispatch routing."""
-    if m <= MAX_VMEM_ENTRIES:
-        return gather_traffic_bytes(m, n)
-    slab, dst = _chunk_plan("tpu", -(-m // BLOCK) * BLOCK,
-                            -(-n // BLOCK) * BLOCK)
-    return chunked_traffic_bytes(m, n, slab, dst)
-
-
 def kernel_round_traffic(pl: "api.GenPlan") -> float:
     """Analytic HBM bytes of the Pallas kernels one round program issues
     (per-device module: each of the lp resident rows runs the vmapped
-    grant/band/count kernels; the compaction batches all lp rows)."""
-    cfg = pl.config
-    p, lp = pl.num_procs, pl.lp
-    e = cfg.edges_per_proc
-    c_r = pl.round_capacity
-    block_cap = stream_block_capacity(e, p, c_r)
-    grant = lp * _gather_bytes(e + pl.urn_budget, p * c_r)
-    band = lp * _gather_bytes(p * c_r, e)
-    hist = lp * histogram_traffic_bytes(e, p)
-    t_in, t_out = _tile_plan("tpu", e, block_cap)
-    compact = band_compact_traffic_bytes(lp, e, block_cap, t_in, t_out)
-    return grant + band + hist + compact
+    census kernel)."""
+    return pl.lp * histogram_traffic_bytes(pl.config.edges_per_proc,
+                                           pl.num_procs)
 
 
 def _leg_record(hlo: str) -> dict:

@@ -32,6 +32,7 @@ import jax
 
 from repro import api
 from repro.core import degree_counts, fit_power_law
+from repro.runtime import spmd
 
 
 def build_specs(args, state, n_dev):
@@ -68,6 +69,7 @@ def build_specs(args, state, n_dev):
 
 
 def main() -> None:
+    spmd.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default=None, choices=sorted(api.PRESETS),
                     help="run a named scenario (overrides the scale flags)")

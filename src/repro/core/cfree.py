@@ -229,22 +229,17 @@ def er_endpoints(words: jax.Array, t: jax.Array, n: int
     return u, v
 
 
-def cfree_endpoints(cfg: CFreeConfig, t: jax.Array, words: jax.Array,
-                    use_kernel: bool = False) -> tuple[jax.Array, jax.Array]:
+def cfree_endpoints(cfg: CFreeConfig, t: jax.Array, words: jax.Array
+                    ) -> tuple[jax.Array, jax.Array]:
     """(u, v) int32 endpoints of global edge indices ``t`` — pure in
-    (words, t); every executor path funnels through here."""
+    (words, t); every executor path funnels through here, and through
+    ``kops.cfree_expand``: the Mosaic kernel on TPU, the jnp functions
+    above elsewhere."""
+    from repro.kernels import ops as kops
     n, _ = cfree_sizes(cfg)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.cfree_expand(t, words, model=cfg.model, n=n,
-                                 ba_degree=cfg.ba_degree,
-                                 thresholds=rmat_thresholds(cfg))
-    if cfg.model == "ba_cfree":
-        return t // cfg.ba_degree, ba_dst(words, t, cfg.ba_degree)
-    if cfg.model == "rmat":
-        levels = n.bit_length() - 1
-        return rmat_endpoints(words, t, levels, *rmat_thresholds(cfg))
-    return er_endpoints(words, t, n)
+    return kops.cfree_expand(t, words, model=cfg.model, n=n,
+                             ba_degree=cfg.ba_degree,
+                             thresholds=rmat_thresholds(cfg))
 
 
 # --- serial oracle ------------------------------------------------------------
@@ -277,23 +272,20 @@ def _cfree_stats(e: int, n: int) -> GenStats:
                     num_vertices=n, exchange_rounds=0, pair_capacity=0)
 
 
-def generate_cfree_host(cfg: CFreeConfig, use_kernel: bool = False
-                        ) -> tuple[EdgeList, GenStats]:
+def generate_cfree_host(cfg: CFreeConfig) -> tuple[EdgeList, GenStats]:
     """Single-device expansion of the full index range."""
     CFreeConfig.validate(cfg)
     n, e = cfree_sizes(cfg)
 
     @jax.jit
     def expand(t):
-        return cfree_endpoints(cfg, t, cfree_words(cfg),
-                               use_kernel=use_kernel)
+        return cfree_endpoints(cfg, t, cfree_words(cfg))
 
     u, v = expand(jnp.arange(e, dtype=jnp.int32))
     return EdgeList(src=u, dst=v, num_vertices=n), _cfree_stats(e, n)
 
 
-def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology,
-                      use_kernel: bool = False):
+def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
     """(jitted_fn, example_args) for the sharded zero-collective program.
 
     The one front-door cfree program: ``P = lp·D`` logical ranks each
@@ -320,7 +312,7 @@ def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology,
 
         def one(rank):
             t = rank * chunk + jnp.arange(chunk, dtype=jnp.int32)
-            u, v = cfree_endpoints(cfg, t, words, use_kernel=use_kernel)
+            u, v = cfree_endpoints(cfg, t, words)
             if chunk * num_procs > e:
                 u, v = blocking.mask_tail((u, v), rank, chunk, e)
             return u, v
@@ -337,7 +329,6 @@ def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology,
 
 def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
                    axis_name: str = "proc", num_procs: Optional[int] = None,
-                   use_kernel: bool = False,
                    topology: Optional[Topology] = None
                    ) -> tuple[EdgeList, GenStats]:
     """Distributed communication-free generation over any topology.
@@ -352,7 +343,7 @@ def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
     topology, mesh = topology_lib.resolve(topology, mesh, axis_name)
     p = num_procs or topology.num_devices
     n, e = cfree_sizes(cfg)
-    fn, args = sharded_expand_fn(cfg, p, topology, use_kernel=use_kernel)
+    fn, args = sharded_expand_fn(cfg, p, topology)
     u, v = fn(*args)
     return EdgeList(src=u, dst=v, num_vertices=n), _cfree_stats(e, n)
 
@@ -371,8 +362,7 @@ class CFreeStream:
     """
 
     def __init__(self, cfg: CFreeConfig, slab_edges: int,
-                 topology: Optional[Topology] = None,
-                 use_kernel: bool = False):
+                 topology: Optional[Topology] = None):
         CFreeConfig.validate(cfg)
         n, e = cfree_sizes(cfg)
         if not 1 <= slab_edges <= 2**31 - 1:
@@ -396,8 +386,7 @@ class CFreeStream:
                 words = cfree_words(cfg)
                 t = (t0_blk[0] + dev * per_dev
                      + jnp.arange(per_dev, dtype=jnp.int32))
-                u, v = cfree_endpoints(cfg, t, words,
-                                       use_kernel=use_kernel)
+                u, v = cfree_endpoints(cfg, t, words)
                 return u[None], v[None]
 
             self._expand = jax.jit(spmd.shard_map(
@@ -409,8 +398,7 @@ class CFreeStream:
 
             @jax.jit
             def expand(t0):
-                return cfree_endpoints(cfg, t_rel + t0, cfree_words(cfg),
-                                       use_kernel=use_kernel)
+                return cfree_endpoints(cfg, t_rel + t0, cfree_words(cfg))
 
             self._expand = expand
 

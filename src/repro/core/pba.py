@@ -95,8 +95,8 @@ def default_pair_capacity(edges_per_proc: int, min_s: int,
     At pod scale (``num_procs`` given) the live exchange buffer becomes the
     binding constraint: the total capacity is clamped so each *logical
     processor's* (P, C_r) int32 round buffer fits 1/16 of device memory
-    (probed via ``runtime.spmd.device_memory_bytes``; fixed fallback on
-    backends without stats). The budget is deliberately per logical
+    (probed via ``runtime.spmd.device_memory_bytes``: an accelerator's
+    reported limit, a fixed budget on host devices). The budget is deliberately per logical
     processor, not per device: the derived capacity must be a pure function
     of (cfg, table) or the host (lp = P) and sharded (lp = P/D) runs of the
     same graph would disagree — a device hosting lp logical processors
@@ -109,7 +109,7 @@ def default_pair_capacity(edges_per_proc: int, min_s: int,
     all_to_alls.
 
     Note the probed memory makes the *default* backend-dependent: a CPU
-    host (fixed fallback) and an accelerator (reported bytes_limit) can
+    host (fixed budget) and an accelerator (reported bytes_limit) can
     derive different capacities at large P, and the capacity is part of the
     graph's identity. Cross-backend validation runs should pin the budget
     explicitly — every generator logs the chosen value in

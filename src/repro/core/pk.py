@@ -172,26 +172,22 @@ def expand_chunk(t_local: jax.Array, base_digits: jax.Array,
     return u_coord, v_coord
 
 
-def generate_pk_host(seed: SeedGraph, cfg: PKConfig,
-                     use_kernel: bool = False) -> tuple[EdgeList, GenStats]:
+def generate_pk_host(seed: SeedGraph, cfg: PKConfig
+                     ) -> tuple[EdgeList, GenStats]:
     """Single-device PK expansion of the full index range."""
+    from repro.kernels import ops as kops
     SeedGraph.validate(seed)
     n, e = pk_sizes(seed, cfg)
     _check_int32(seed, cfg, e)
     su, sv = jnp.asarray(seed.u), jnp.asarray(seed.v)
     base = jnp.zeros((cfg.levels,), jnp.int32)
     t = jnp.arange(e, dtype=jnp.int32)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        u, v = kops.pk_expand(t, base, su, sv, seed.num_vertices,
-                              seed.num_edges, cfg.levels, cfg.noise,
-                              cfg.delete_prob, cfg.seed, rank=0)
-    else:
-        u, v = jax.jit(
-            functools.partial(expand_chunk, n0=seed.num_vertices,
-                              e0=seed.num_edges, levels=cfg.levels, cfg=cfg,
-                              rank=0)
-        )(t, base, su, sv)
+    u, v = jax.jit(
+        functools.partial(kops.pk_expand, n0=seed.num_vertices,
+                          e0=seed.num_edges, levels=cfg.levels,
+                          noise=cfg.noise, delete_prob=cfg.delete_prob,
+                          seed=cfg.seed, rank=0)
+    )(t, base, su, sv)
     edges = EdgeList(src=u, dst=v, num_vertices=n)
     emitted = int(jnp.sum(u >= 0))
     return edges, GenStats(requested_edges=e, emitted_edges=emitted,
@@ -200,7 +196,6 @@ def generate_pk_host(seed: SeedGraph, cfg: PKConfig,
 
 def generate_pk(seed: SeedGraph, cfg: PKConfig,
                 mesh: Optional[Mesh] = None, axis_name: str = "proc",
-                use_kernel: bool = False,
                 topology: Optional[Topology] = None
                 ) -> tuple[EdgeList, GenStats]:
     """Distributed PK: contiguous index range per device, zero communication.
@@ -226,16 +221,12 @@ def generate_pk(seed: SeedGraph, cfg: PKConfig,
     su, sv = jnp.asarray(seed.u), jnp.asarray(seed.v)
 
     def body(base_blk):
+        from repro.kernels import ops as kops
         rank = blocking.device_index(topology)
         t = jnp.arange(chunk, dtype=jnp.int32)
-        if use_kernel:
-            from repro.kernels import ops as kops
-            u, v = kops.pk_expand(t, base_blk[0], su, sv, seed.num_vertices,
-                                  seed.num_edges, cfg.levels, cfg.noise,
-                                  cfg.delete_prob, cfg.seed, rank=rank)
-        else:
-            u, v = expand_chunk(t, base_blk[0], su, sv, seed.num_vertices,
-                                seed.num_edges, cfg.levels, cfg, rank)
+        u, v = kops.pk_expand(t, base_blk[0], su, sv, seed.num_vertices,
+                              seed.num_edges, cfg.levels, cfg.noise,
+                              cfg.delete_prob, cfg.seed, rank=rank)
         if chunk * num_procs > e:
             # mask indices past the global edge count (last device's tail)
             u, v = blocking.mask_tail((u, v), rank, chunk, e)

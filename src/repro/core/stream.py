@@ -47,8 +47,7 @@ from repro.core.pba import (PBAConfig, _derived_pair_capacity, _phase1,
                             _phase2_pool, occurrence_rank,
                             pba_stream_round_block, pba_stream_setup_block,
                             stream_block_capacity)
-from repro.core.pk import (PKConfig, SeedGraph, decompose_base, expand_chunk,
-                           pk_sizes)
+from repro.core.pk import PKConfig, SeedGraph, decompose_base, pk_sizes
 from repro.runtime import blocking, spmd, streaming
 from repro.runtime import topology as topology_lib
 from repro.runtime.topology import Topology
@@ -505,7 +504,9 @@ class PKStream:
 
         @jax.jit
         def expand(t, base, rank):
-            return expand_chunk(t, base, su, sv, n0, e0, levels, cfg, rank)
+            from repro.kernels import ops as kops
+            return kops.pk_expand(t, base, su, sv, n0, e0, levels, cfg.noise,
+                                  cfg.delete_prob, cfg.seed, rank)
 
         self._expand = expand
         self._t = jnp.arange(slab_edges, dtype=jnp.int32)

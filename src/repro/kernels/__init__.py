@@ -100,13 +100,10 @@ def _edge_resolve_meta() -> dict:
         "slab_entries": slab_entries(),
         "max_slabs": MAX_SLABS,
         "max_chunked_entries": MAX_CHUNKED_ENTRIES,
-        "oversize_fallback": (
-            "ops.resolve_step/ops.gather stay VMEM-resident up to "
-            "max_vmem_entries, then hierarchically chunk the source into "
-            "slab-sized VMEM tiles up to max_chunked_entries; only past "
-            "that do they fall back to the jnp reference, counted per "
-            "size bucket in repro.kernels.ops.FALLBACK_EVENTS "
-            "('resolve_step_oversize:le<pow2>' / 'gather_oversize:le<pow2>')"),
+        "routing": (
+            "not on the hot path: ops.resolve_step/ops.gather run the XLA "
+            "gather on every backend, since this kernel does not lower for "
+            "TPU v5e"),
     }
 
 
@@ -143,9 +140,10 @@ def _band_compact_meta() -> dict:
     return {
         "in_block": IN_BLOCK,
         "out_block": OUT_BLOCK,
-        "note": ("fused predicated prefix-sum compaction replacing the "
-                 "round program's argsort/take_along_axis sequence; tile "
-                 "shapes autotuned per size (dispatch.autotune)"),
+        "note": ("fused predicated prefix-sum compaction; not on the hot "
+                 "path: ops.band_compact runs the XLA sort on every "
+                 "backend, since this kernel does not lower for TPU v5e; "
+                 "tile shapes autotuned per size (dispatch.autotune)"),
     }
 
 

@@ -1,20 +1,22 @@
-"""Jitted dispatch wrappers over the Pallas kernels.
+"""Dispatch wrappers for the generators' hot spots.
 
-Dispatch policy (kernels/dispatch.py, per-call overridable):
+The histogram, PK expansion and cfree expansion dispatch by mode
+(kernels/dispatch.py, per-call overridable):
   * TPU backend        -> compiled Pallas kernels.
   * elsewhere          -> pure-jnp reference (XLA:CPU) — interpret-mode Pallas
                           is for *correctness tests*, not speed, so the
                           library only routes through it when forced via
                           REPRO_PALLAS=interpret (used by the test suite).
 
+PBA's gathers (pointer doubling, grant, band) and its band compaction run
+the XLA formulation in every mode: the ``edge_resolve`` and
+``band_compact`` kernels do not lower for TPU.
+
 The kernel functions themselves default ``interpret=None`` and resolve the
 mode through the same probe, so direct kernel calls and these wrappers can
 never disagree about execution mode.
 """
 from __future__ import annotations
-
-import functools
-import logging
 
 import jax
 import jax.numpy as jnp
@@ -25,11 +27,6 @@ from repro.kernels.dispatch import mode as _mode
 from repro.kernels.cfree_expand import cfree_expand_pallas
 from repro.kernels.pk_expand import pk_expand_pallas
 from repro.kernels.histogram import histogram_pallas
-from repro.kernels.band_compact import band_compact_pallas
-from repro.kernels.edge_resolve import (MAX_CHUNKED_ENTRIES,
-                                        MAX_VMEM_ENTRIES,
-                                        gather_chunked_pallas, gather_pallas,
-                                        resolve_step_pallas)
 
 
 def pk_expand(t_local, base_digits, seed_u, seed_v, n0: int, e0: int,
@@ -79,30 +76,11 @@ def histogram(values: jax.Array, num_bins: int) -> jax.Array:
     return histogram_pallas(values, num_bins)
 
 
-_log = logging.getLogger(__name__)
-
 #: Trace-time kernel-fallback counters, keyed "event:le<pow2-size-bucket>".
-#: A dispatch wrapper that wanted a Pallas kernel but had to route to the
-#: jnp reference (e.g. a source past the chunked-gather bound) increments
-#: its event here, once per trace — the decision is made on static shapes,
-#: so one count corresponds to one compiled program, not one execution.
-#: The size bucket (smallest power of two >= the offending dimension)
-#: makes distinct shape regimes distinct events without unbounded keys.
-#: pallascheck's inventory (``python -m repro.analysis kernels``) reports
-#: these and GenStats carries a snapshot, so capacity fallbacks in a
-#: production spec are visible in the result object, not just the log.
+#: No dispatch wrapper records an event since gather and compaction run
+#: the XLA formulation unconditionally; GenStats and pallascheck's
+#: inventory still carry the (empty) snapshot.
 FALLBACK_EVENTS: dict[str, int] = {}
-
-
-def _bucket(n: int) -> int:
-    """Smallest power of two >= n (the fallback shape bucket)."""
-    return 1 << max(int(n) - 1, 0).bit_length()
-
-
-def _record_fallback(event: str, size: int, detail: str) -> None:
-    key = f"{event}:le{_bucket(size)}"
-    FALLBACK_EVENTS[key] = FALLBACK_EVENTS.get(key, 0) + 1
-    _log.info("kernel fallback %s: %s", key, detail)
 
 
 def fallback_counts() -> dict[str, int]:
@@ -111,69 +89,27 @@ def fallback_counts() -> dict[str, int]:
 
 
 def resolve_step(ptr: jax.Array) -> jax.Array:
-    """One ptr[ptr] pass via the Pallas kernels.
-
-    Sources up to ``MAX_VMEM_ENTRIES`` stay VMEM-resident; past that the
-    hierarchically chunked gather (src == idx) takes over up to
-    ``MAX_CHUNKED_ENTRIES``. Only beyond the chunked bound does the whole
-    array fall back to the jnp reference, counted per size bucket in
-    ``FALLBACK_EVENTS`` so the detour is observable.
-    """
-    mode = _mode()
-    m = ptr.shape[0]
-    if mode == "off":
-        return ref.resolve_step_ref(ptr)
-    if m <= MAX_VMEM_ENTRIES:
-        return resolve_step_pallas(ptr)
-    if m <= MAX_CHUNKED_ENTRIES:
-        return gather_chunked_pallas(ptr, ptr)
-    _record_fallback(
-        "resolve_step_oversize", m,
-        f"m={m} > MAX_CHUNKED_ENTRIES={MAX_CHUNKED_ENTRIES}; resolving via "
-        "the jnp reference")
+    """One ptr[ptr] pointer-doubling pass, as an XLA gather on every
+    backend (Mosaic cannot gather from a multi-MiB VMEM source)."""
     return ref.resolve_step_ref(ptr)
 
 
 def gather(src: jax.Array, idx: jax.Array) -> jax.Array:
     """values = src[..., clip(idx)] along the last axis (ref.gather_ref
-    contract) via the resident or chunked gather kernel.
+    contract), as an XLA gather on every backend.
 
-    Accepts a 1-D shared source with any-rank indices (flattened through
-    one kernel call), or batched rows: src (r, m) with idx (r, n). The
-    per-row source length picks the regime, mirroring resolve_step.
+    Accepts a 1-D shared source with any-rank indices, or batched rows:
+    src (r, m) with idx (r, n).
     """
-    mode = _mode()
-    m = src.shape[-1]
-    if mode == "off":
-        if src.ndim == 1 and idx.ndim > 1:
-            return ref.gather_ref(src, idx.reshape(-1)).reshape(idx.shape)
-        return ref.gather_ref(src, idx)
-    if m <= MAX_VMEM_ENTRIES:
-        fn = gather_pallas
-    elif m <= MAX_CHUNKED_ENTRIES:
-        fn = gather_chunked_pallas
-    else:
-        _record_fallback(
-            "gather_oversize", m,
-            f"m={m} > MAX_CHUNKED_ENTRIES={MAX_CHUNKED_ENTRIES}; gathering "
-            "via the jnp reference")
-        if src.ndim == 1 and idx.ndim > 1:
-            return ref.gather_ref(src, idx.reshape(-1)).reshape(idx.shape)
-        return ref.gather_ref(src, idx)
-    if src.ndim == 1:
-        flat = idx.reshape(-1)
-        return fn(src, flat).reshape(idx.shape)
-    if src.ndim == 2 and idx.ndim == 2:
-        return jax.vmap(fn)(src, idx)
-    raise ValueError(f"gather: unsupported ranks {src.ndim}/{idx.ndim}")
+    if src.ndim == 1 and idx.ndim > 1:
+        return ref.gather_ref(src, idx.reshape(-1)).reshape(idx.shape)
+    return ref.gather_ref(src, idx)
 
 
 def band_compact(u: jax.Array, v: jax.Array, band: jax.Array,
                  block_cap: int) -> tuple[jax.Array, jax.Array]:
-    """Fused predicated compaction (ref.band_compact_ref contract):
-    per row, band-selected (u, v) move to the front in index order, -1
-    elsewhere, truncated to block_cap."""
-    mode = _mode()
-    if mode == "off":
-        return ref.band_compact_ref(u, v, band, block_cap)
-    return band_compact_pallas(u, v, band, block_cap)
+    """Predicated compaction (ref.band_compact_ref contract): per row,
+    band-selected (u, v) move to the front in index order, -1 elsewhere,
+    truncated to block_cap. The XLA sort runs on every backend: it costs
+    O(e log e) where the one-hot Pallas kernel costs O(e * cap)."""
+    return ref.band_compact_ref(u, v, band, block_cap)

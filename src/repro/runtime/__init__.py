@@ -2,10 +2,9 @@
 distributed execution in this repo.
 
 Submodules:
-  spmd     — shard_map / make_mesh shims over the installed JAX's API
-             (jax.shard_map + check_vma vs jax.experimental.shard_map +
-             check_rep), probed once at import; device_kind/count/memory
-             probes; the axis_index gateway.
+  spmd     — the shard_map / make_mesh gateway; device_kind/count/memory
+             probes; the axis_index gateway; the persistent compile
+             cache an entry point turns on.
   topology — the Topology dataclass: mesh axes + sizes + the P = lp * D
              factorization (host / flat / pods constructors).
   blocking — logical-processors-over-devices primitives: map_logical,

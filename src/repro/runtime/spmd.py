@@ -1,137 +1,66 @@
-"""Version-portable shard_map / mesh construction.
+"""shard_map / mesh construction, device probes and the compile cache.
 
-JAX moved its manual-SPMD entry point across releases:
-
-  * 0.4.x / 0.5.x:  ``jax.experimental.shard_map.shard_map`` with
-    ``check_rep=`` (replication check) and ``auto=`` (set of axes that stay
-    under the automatic partitioner).
-  * 0.6+:  ``jax.shard_map`` with ``check_vma=`` (the renamed check) and
-    ``axis_names=`` (set of axes that are *manual* — the complement of
-    ``auto``).
-
-Similarly ``jax.make_mesh`` only grew ``axis_types=`` /
-``jax.sharding.AxisType`` in 0.6+.
-
-This module probes the installed JAX once at import and exposes a single
-:func:`shard_map` / :func:`make_mesh` that accepts either spelling of each
-kwarg and translates to whatever the backend understands. Every call site in
-the repo goes through here; nothing else imports the raw APIs (enforced by
+Every call site in the repo goes through here; nothing else imports the
+raw shard_map / mesh-typing APIs (enforced by
 tests/test_runtime.py::test_no_raw_shard_map_outside_runtime).
 """
 from __future__ import annotations
 
-import inspect
+import os
 from typing import Any, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-# --- one-time probe ---------------------------------------------------------
-
-_IMPL = getattr(jax, "shard_map", None)
-if _IMPL is not None:
-    _IMPL_NAME = "jax.shard_map"
-else:
-    from jax.experimental.shard_map import shard_map as _IMPL  # type: ignore
-
-    _IMPL_NAME = "jax.experimental.shard_map.shard_map"
-
-_IMPL_PARAMS = frozenset(inspect.signature(_IMPL).parameters)
-# replication/varying-manual-axes check: renamed check_rep -> check_vma
-_CHECK_KWARG = ("check_vma" if "check_vma" in _IMPL_PARAMS
-                else "check_rep" if "check_rep" in _IMPL_PARAMS else None)
-# partial-manual spelling: new API names the *manual* axes, old API names the
-# *automatic* complement
-_MANUAL_KWARG = ("axis_names" if "axis_names" in _IMPL_PARAMS
-                 else "auto" if "auto" in _IMPL_PARAMS else None)
-
-_MAKE_MESH_HAS_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters
-    and hasattr(jax.sharding, "AxisType"))
+from jax.sharding import AxisType, Mesh
 
 
 def api_info() -> dict:
-    """What the probe resolved — for verify scripts and debugging."""
-    return {
-        "jax_version": jax.__version__,
-        "shard_map_impl": _IMPL_NAME,
-        "check_kwarg": _CHECK_KWARG,
-        "manual_axes_kwarg": _MANUAL_KWARG,
-        "make_mesh_axis_types": _MAKE_MESH_HAS_AXIS_TYPES,
-    }
+    """The JAX entry points this module wraps — for verify scripts."""
+    return {"jax_version": jax.__version__,
+            "shard_map_impl": "jax.shard_map"}
 
 
 # --- shard_map --------------------------------------------------------------
 
 def shard_map(f, mesh, in_specs, out_specs, *,
               check_vma: Optional[bool] = None,
-              check_rep: Optional[bool] = None,
               axis_names: Optional[Any] = None):
-    """Map ``f`` over shards of a mesh, portably across JAX versions.
+    """``jax.shard_map`` over ``mesh``.
 
-    check_vma / check_rep are aliases (new / old name of the same knob);
-    pass at most one. ``axis_names`` is the *new*-API spelling: the set of
-    mesh axes that are manual inside ``f`` (None => all of them); on old
-    JAX it is translated to ``auto = mesh.axis_names - axis_names``.
+    ``axis_names``: the set of mesh axes that are manual inside ``f``
+    (None => all of them).
     """
-    if check_vma is not None and check_rep is not None:
-        raise TypeError("pass check_vma or check_rep, not both")
-    check = check_vma if check_vma is not None else check_rep
     kwargs: dict[str, Any] = {}
-    if check is not None and _CHECK_KWARG is not None:
-        kwargs[_CHECK_KWARG] = check
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
     if axis_names is not None:
-        if _MANUAL_KWARG == "axis_names":
-            kwargs["axis_names"] = set(axis_names)
-        elif _MANUAL_KWARG == "auto":
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        else:  # pragma: no cover - every known impl has one of the two
-            raise NotImplementedError(
-                f"{_IMPL_NAME} supports no partial-manual kwarg")
-    return _IMPL(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                 **kwargs)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 # --- mesh construction ------------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Any = "auto", devices=None):
-    """``jax.make_mesh`` with the ``axis_types=`` drift papered over.
+    """``jax.make_mesh`` with every axis of one type.
 
     axis_types: "auto" (default) / "explicit", applied to every axis, or an
-    explicit tuple passed through verbatim. On JAX without AxisType the
-    "auto" request is dropped — 0.4.x meshes behave as fully automatic,
-    which is what it asks for; anything else raises, since those semantics
-    cannot be honored there.
+    explicit tuple passed through verbatim.
     """
-    kwargs: dict[str, Any] = {}
+    if axis_types == "auto":
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    elif axis_types == "explicit":
+        axis_types = (AxisType.Explicit,) * len(axis_names)
+    kwargs: dict[str, Any] = {"axis_types": tuple(axis_types)}
     if devices is not None:
         kwargs["devices"] = devices
-    if _MAKE_MESH_HAS_AXIS_TYPES:
-        at = jax.sharding.AxisType
-        if axis_types == "auto":
-            axis_types = (at.Auto,) * len(axis_names)
-        elif axis_types == "explicit":
-            axis_types = (at.Explicit,) * len(axis_names)
-        kwargs["axis_types"] = tuple(axis_types)
-    elif axis_types != "auto":
-        raise NotImplementedError(
-            f"axis_types={axis_types!r} needs jax.sharding.AxisType, which "
-            f"jax {jax.__version__} does not provide")
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across JAX versions.
-
-    0.4.x returns a one-element list of dicts (per partition); newer JAX
-    returns the dict directly (or None when XLA offers no analysis).
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, or {} when XLA offers no analysis."""
+    return compiled.cost_analysis() or {}
 
 
 def axis_index(axis_name) -> Any:
@@ -151,27 +80,50 @@ def device_kind() -> str:
     return str(jax.devices()[0].device_kind)
 
 
-_DEFAULT_DEVICE_MEMORY = 8 << 30  # conservative HBM guess when unprobeable
+#: Memory budget assumed for a host (CPU) device, which reports none. It
+#: is fixed so the derived values it feeds (the pair-capacity heuristic)
+#: are the same in every process: host/sharded bit-parity needs that.
+HOST_DEVICE_MEMORY = 8 << 30
 
 
-def device_memory_bytes(default: int = _DEFAULT_DEVICE_MEMORY) -> int:
+def device_memory_bytes() -> int:
     """Per-device memory budget in bytes.
 
-    Accelerators report ``bytes_limit`` via ``memory_stats()``; host/CPU
-    devices usually report nothing, so a fixed conservative default keeps
-    derived values (e.g. the pair-capacity heuristic) deterministic across
-    processes — required for host/sharded bit-parity.
+    Accelerators report ``bytes_limit`` via ``memory_stats()``, and an
+    accelerator that reports none is an error: the capacity this feeds
+    is part of the graph's identity, so it is never guessed there. Host
+    devices report nothing and get :data:`HOST_DEVICE_MEMORY`.
     """
     dev = jax.devices()[0]
-    try:
-        stats = dev.memory_stats()
-    except Exception:  # backend offers no stats
-        stats = None
-    if stats:
-        for key in ("bytes_limit", "bytes_reservable_limit"):
-            if stats.get(key):
-                return int(stats[key])
-    return default
+    stats = dev.memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    if dev.platform != "cpu":
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            f"bytes_limit in memory_stats() ({sorted(stats)})")
+    return HOST_DEVICE_MEMORY
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and this sets
+    nothing. Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache``, never a temporary directory, a pid or a
+    time, so a later run of the checkout finds what an earlier one
+    compiled. Call it first
+    thing in a script; library imports never call it. Returns the
+    directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def make_proc_mesh(num_procs: int = 0, axis_name: str = "proc",

@@ -131,34 +131,25 @@ def test_registry_boundary_case_lands_on_budget():
 # --- fallback observability --------------------------------------------------
 
 def test_oversize_resolve_fallback_is_counted(monkeypatch):
+    """Gather routing records no fallback in any mode or regime: the XLA
+    formulation is the path, not a detour. The routing is decided on
+    static shapes at trace time, so eval_shape exercises it without
+    allocating ~256 MiB."""
     import jax
 
     from repro.kernels import ops
-    from repro.kernels.edge_resolve import MAX_CHUNKED_ENTRIES
+    from repro.kernels.edge_resolve import (MAX_CHUNKED_ENTRIES,
+                                            MAX_VMEM_ENTRIES)
 
-    monkeypatch.setenv("REPRO_PALLAS", "interpret")
-    monkeypatch.setattr(ops, "FALLBACK_EVENTS", {})
-    # past even the chunked bound -> jnp reference, counted per size
-    # bucket. The routing decision is made on static shapes at trace
-    # time, so eval_shape triggers it without allocating ~256 MiB.
-    m = MAX_CHUNKED_ENTRIES + 1
-    spec = jax.ShapeDtypeStruct((m,), jax.numpy.int32)
-    out = jax.eval_shape(ops.resolve_step, spec)
-    assert out.shape == (m,)
-    key = f"resolve_step_oversize:le{ops._bucket(m)}"
-    assert ops.fallback_counts() == {key: 1}
-    # the chunked regime itself is a kernel path, not a fallback
-    monkeypatch.setattr(ops, "FALLBACK_EVENTS", {})
-    from repro.kernels.edge_resolve import MAX_VMEM_ENTRIES
-    jax.eval_shape(ops.resolve_step,
-                   jax.ShapeDtypeStruct((MAX_VMEM_ENTRIES + 1,),
-                                        jax.numpy.int32))
-    assert ops.fallback_counts() == {}
-    # in forced-off mode the reference IS the normal path: not an event
-    monkeypatch.setenv("REPRO_PALLAS", "off")
-    monkeypatch.setattr(ops, "FALLBACK_EVENTS", {})
-    jax.eval_shape(ops.resolve_step, spec)
-    assert ops.fallback_counts() == {}
+    for mode in ("interpret", "off"):
+        monkeypatch.setenv("REPRO_PALLAS", mode)
+        monkeypatch.setattr(ops, "FALLBACK_EVENTS", {})
+        for m in (MAX_VMEM_ENTRIES + 1, MAX_CHUNKED_ENTRIES + 1):
+            spec = jax.ShapeDtypeStruct((m,), jax.numpy.int32)
+            assert jax.eval_shape(ops.resolve_step, spec).shape == (m,)
+            rows = jax.ShapeDtypeStruct((2, m), jax.numpy.int32)
+            assert jax.eval_shape(ops.gather, rows, rows).shape == (2, m)
+        assert ops.fallback_counts() == {}
 
 
 # --- inventory / gate plumbing -----------------------------------------------

@@ -51,10 +51,8 @@ def test_front_door_only_outside_src():
 
 def test_api_info_resolved():
     info = spmd.api_info()
-    assert info["shard_map_impl"] in (
-        "jax.shard_map", "jax.experimental.shard_map.shard_map")
-    assert info["check_kwarg"] in ("check_vma", "check_rep")
-    assert info["manual_axes_kwarg"] in ("axis_names", "auto")
+    assert info == {"jax_version": jax.__version__,
+                    "shard_map_impl": "jax.shard_map"}
 
 
 # --- shim, single device ----------------------------------------------------
@@ -72,13 +70,14 @@ def test_shard_map_check_kwarg_aliases():
     mesh = spmd.make_proc_mesh(1)
     body, in_s, out_s = _psum_fn(mesh)
     x = jnp.arange(4, dtype=jnp.int32)
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
+    for kw in ({"check_vma": False}, {"check_vma": True}, {}):
         out = jax.jit(spmd.shard_map(body, mesh=mesh, in_specs=in_s,
                                      out_specs=out_s, **kw))(x)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
 
 def test_shard_map_rejects_both_check_kwargs():
+    """The pre-0.6 spelling ``check_rep`` is not accepted any more."""
     mesh = spmd.make_proc_mesh(1)
     body, in_s, out_s = _psum_fn(mesh)
     with pytest.raises(TypeError):
@@ -87,17 +86,63 @@ def test_shard_map_rejects_both_check_kwargs():
 
 
 def test_make_mesh_and_helpers():
+    from jax.sharding import AxisType
     mesh = spmd.make_mesh((1, 1), ("data", "model"), axis_types="auto")
     assert spmd.mesh_size(mesh) == 1
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
     proc = spmd.make_proc_mesh(1)
     assert proc.axis_names == ("proc",)
     assert spmd.ensure_mesh(proc) is proc
     assert spmd.ensure_mesh(None, axis_name="x").axis_names == ("x",)
     with pytest.raises(ValueError):
         spmd.make_proc_mesh(4096)
-    if not spmd.api_info()["make_mesh_axis_types"]:
-        with pytest.raises(NotImplementedError):  # can't honor on old JAX
-            spmd.make_mesh((1,), ("data",), axis_types="explicit")
+    explicit = spmd.make_mesh((1,), ("data",), axis_types="explicit")
+    assert explicit.axis_types == (AxisType.Explicit,)
+
+
+# --- device probes and the compile cache -------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.device_kind, self._stats = platform, "fake", stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,expect", [
+    ("tpu", {"bytes_limit": 16 << 30}, 16 << 30),
+    ("tpu", None, RuntimeError),
+    ("tpu", {"bytes_in_use": 1}, RuntimeError),
+    ("cpu", None, spmd.HOST_DEVICE_MEMORY),
+])
+def test_device_memory_bytes_never_guesses_on_an_accelerator(
+        monkeypatch, platform, stats, expect):
+    """The probed budget feeds the pair capacity, which is part of the
+    graph's identity: an accelerator without ``bytes_limit`` raises, and
+    only a host device gets the fixed budget."""
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeDevice(platform, stats)])
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError, match="bytes_limit"):
+            spmd.device_memory_bytes()
+    else:
+        assert spmd.device_memory_bytes() == expect
+
+
+def test_compile_cache_follows_env_else_checkout(monkeypatch, tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert spmd.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before  # left to JAX
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = spmd.enable_compile_cache()
+        assert path == str(REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert spmd.enable_compile_cache() == path  # fixed, not per call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_dp_sync_rejects_wrong_leading_dim():

@@ -296,8 +296,6 @@ def kernels_main(argv: Optional[Sequence[str]] = None) -> int:
               f"case(s), {n_calls} pallas_call(s) against "
               f"{inv['budget']['vmem_bytes']} B VMEM budget "
               f"({inv['budget']['backend']})")
-        for event, count in sorted(inv["fallback_events"].items()):
-            print(f"pallascheck: fallback {event}: {count} trace(s)")
     if findings:
         for f in findings:
             print(f"pallascheck FAIL {f.format()}", file=sys.stderr)

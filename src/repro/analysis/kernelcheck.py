@@ -362,7 +362,6 @@ def run_registry(backend: str = "tpu", execute: bool = True,
                                              execute=execute)
         findings.extend(f)
 
-    from repro.kernels import ops
     inv = {
         "schema": 1,
         "jax_version": jax.__version__,
@@ -370,7 +369,6 @@ def run_registry(backend: str = "tpu", execute: bool = True,
                    "vmem_bytes": vmem_budget_bytes(backend),
                    "model": "resident + 2x double-buffered gridded blocks"},
         "kernels": kernels,
-        "fallback_events": ops.fallback_counts(),
         "findings": [f.to_json() for f in findings],
         "ok": not findings,
     }

@@ -44,7 +44,7 @@ from repro.core.pba import PBAConfig
 from repro.core.pk import PKConfig, SeedGraph
 from repro.core.spec import (CFREE_MODELS, EXECUTIONS, MODELS, SINKS,
                              GraphSpec)
-from repro.runtime import spmd, streaming
+from repro.runtime import spans, spmd, streaming
 from repro.runtime.topology import Topology
 
 __all__ = ["GraphSpec", "GenPlan", "GenResult", "plan", "generate",
@@ -452,17 +452,19 @@ def plan(spec: GraphSpec) -> GenPlan:
     don't cover P, logical-processor counts that do not factor over the
     device topology, missing shard sinks, and int32 overflows.
     """
-    if spec.model not in MODELS:
-        raise ValueError(f"unknown model {spec.model!r}: one of {MODELS}")
-    if spec.sink not in SINKS:
-        raise ValueError(f"unknown sink {spec.sink!r}: one of {SINKS}")
-    if spec.sink == "shards" and not spec.out_dir:
-        raise ValueError("sink='shards' needs out_dir")
-    if spec.model == "pba":
-        return _plan_pba(spec)
-    if spec.model == "pk":
-        return _plan_pk(spec)
-    return _plan_cfree(spec)
+    with spans.span("repro.plan", model=str(spec.model)):
+        if spec.model not in MODELS:
+            raise ValueError(
+                f"unknown model {spec.model!r}: one of {MODELS}")
+        if spec.sink not in SINKS:
+            raise ValueError(f"unknown sink {spec.sink!r}: one of {SINKS}")
+        if spec.sink == "shards" and not spec.out_dir:
+            raise ValueError("sink='shards' needs out_dir")
+        if spec.model == "pba":
+            return _plan_pba(spec)
+        if spec.model == "pk":
+            return _plan_pk(spec)
+        return _plan_cfree(spec)
 
 
 # --- generate -----------------------------------------------------------------
@@ -489,10 +491,12 @@ def _edges_from_stream(stream, overlap: bool = True
         for block in stream.iter_blocks():
             srcs.append(block.src)
             dsts.append(block.dst)
-    src = np.concatenate(srcs) if srcs else np.empty(0, np.int32)
-    dst = np.concatenate(dsts) if dsts else np.empty(0, np.int32)
-    edges = EdgeList(src=jnp.asarray(src), dst=jnp.asarray(dst),
-                     num_vertices=stream.num_vertices)
+    with spans.span("repro.sink.assemble",
+                    bytes=sum(a.nbytes for a in srcs + dsts)):
+        src = np.concatenate(srcs) if srcs else np.empty(0, np.int32)
+        dst = np.concatenate(dsts) if dsts else np.empty(0, np.int32)
+        edges = EdgeList(src=jnp.asarray(src), dst=jnp.asarray(dst),
+                         num_vertices=stream.num_vertices)
     return edges, stream_lib.stream_stats(stream, int(len(src)))
 
 
@@ -518,10 +522,17 @@ def generate(plan_or_spec: Union[GenPlan, GraphSpec]) -> GenResult:
 
     Dispatches to the internal executors — bit-identical to calling the
     legacy entry points directly with the plan's resolved arguments (the
-    parity suite in tests/test_api.py pins this).
+    parity suite in tests/test_api.py pins this). Every span the call
+    opens carries one new graph id (:mod:`repro.runtime.spans`).
     """
-    pl = (plan_or_spec if isinstance(plan_or_spec, GenPlan)
-          else plan(plan_or_spec))
+    with spans.graph():
+        pl = (plan_or_spec if isinstance(plan_or_spec, GenPlan)
+              else plan(plan_or_spec))
+        with spans.span("repro.generate", executor=pl.executor):
+            return _execute(pl)
+
+
+def _execute(pl: GenPlan) -> GenResult:
     spec = pl.spec
 
     if pl.execution == "streamed":

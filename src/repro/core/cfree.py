@@ -46,7 +46,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import rng as rng_lib
 from repro.core.graph import EdgeList, GenStats
-from repro.runtime import blocking, spmd
+from repro.runtime import blocking, spans, spmd
 from repro.runtime import topology as topology_lib
 from repro.runtime.topology import Topology
 
@@ -296,35 +296,36 @@ def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
     auditor registrations, so every layer inspects the same program. The
     input is a per-device token that only pins the program to the mesh.
     """
-    n, e = cfree_sizes(cfg)
-    d = topo.num_devices
-    lp = topo.lp(num_procs)
-    chunk = -(-e // num_procs)
-    if chunk > 2**31 - 1:
-        raise ValueError(f"per-rank chunk {chunk} exceeds int32")
-    mesh = topo.build_mesh()
-    spec = topo.spec_axes
+    with spans.span("repro.build", program="cfree_expand"):
+        n, e = cfree_sizes(cfg)
+        d = topo.num_devices
+        lp = topo.lp(num_procs)
+        chunk = -(-e // num_procs)
+        if chunk > 2**31 - 1:
+            raise ValueError(f"per-rank chunk {chunk} exceeds int32")
+        mesh = topo.build_mesh()
+        spec = topo.spec_axes
 
-    def body(tok):
-        del tok  # mesh token only
-        words = cfree_words(cfg)
-        ranks = blocking.logical_ranks(lp, topo)
+        def body(tok):
+            del tok  # mesh token only
+            words = cfree_words(cfg)
+            ranks = blocking.logical_ranks(lp, topo)
 
-        def one(rank):
-            t = rank * chunk + jnp.arange(chunk, dtype=jnp.int32)
-            u, v = cfree_endpoints(cfg, t, words)
-            if chunk * num_procs > e:
-                u, v = blocking.mask_tail((u, v), rank, chunk, e)
-            return u, v
+            def one(rank):
+                t = rank * chunk + jnp.arange(chunk, dtype=jnp.int32)
+                u, v = cfree_endpoints(cfg, t, words)
+                if chunk * num_procs > e:
+                    u, v = blocking.mask_tail((u, v), rank, chunk, e)
+                return u, v
 
-        u, v = blocking.map_logical(one, ranks)
-        return u[None], v[None]
+            u, v = blocking.map_logical(one, ranks)
+            return u[None], v[None]
 
-    fn = jax.jit(spmd.shard_map(
-        body, mesh=mesh, in_specs=(P(spec),),
-        out_specs=(P(spec, None, None), P(spec, None, None)),
-        check_vma=False))
-    return fn, (jnp.zeros((d,), jnp.int32),)
+        fn = jax.jit(spmd.shard_map(
+            body, mesh=mesh, in_specs=(P(spec),),
+            out_specs=(P(spec, None, None), P(spec, None, None)),
+            check_vma=False))
+        return fn, (jnp.zeros((d,), jnp.int32),)
 
 
 def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
@@ -344,7 +345,8 @@ def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
     p = num_procs or topology.num_devices
     n, e = cfree_sizes(cfg)
     fn, args = sharded_expand_fn(cfg, p, topology)
-    u, v = fn(*args)
+    with spans.span("repro.dispatch", program="cfree_expand"):
+        u, v = fn(*args)
     return EdgeList(src=u, dst=v, num_vertices=n), _cfree_stats(e, n)
 
 

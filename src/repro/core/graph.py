@@ -59,11 +59,6 @@ class GenStats:
     pair_capacity: the per-(sender, receiver) exchange budget C the run
     used — explicit from the config or the derived latency/memory-aware
     default (0 for generators without an exchange, e.g. PK).
-    fallback_counts: snapshot of the trace-time kernel-fallback counters
-    (repro.kernels.ops.FALLBACK_EVENTS, keyed "event:le<pow2-bucket>") at
-    the time the result was assembled — empty when every dispatch stayed
-    on a Pallas kernel (or the run never routed through the kernel
-    wrappers at all, e.g. forced-off mode).
     """
 
     requested_edges: int
@@ -72,7 +67,6 @@ class GenStats:
     num_vertices: int
     exchange_rounds: int = 1
     pair_capacity: int = 0
-    fallback_counts: dict = dataclasses.field(default_factory=dict)
 
     @property
     def drop_fraction(self) -> float:

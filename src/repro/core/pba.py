@@ -511,13 +511,11 @@ def generate_pba(cfg: PBAConfig, table: FactionTable,
     edges = EdgeList(src=u, dst=v, num_vertices=n)
     requested = num_procs * cfg.edges_per_proc
     dropped_n = int(dropped[0])
-    from repro.kernels import ops as kops
     stats = GenStats(requested_edges=requested,
                      emitted_edges=requested - dropped_n,
                      dropped_edges=dropped_n, num_vertices=n,
                      exchange_rounds=int(rounds[0]),
-                     pair_capacity=pair_capacity,
-                     fallback_counts=kops.fallback_counts())
+                     pair_capacity=pair_capacity)
     return edges, stats
 
 
@@ -566,14 +564,12 @@ def generate_pba_sharded(cfg: PBAConfig, table: FactionTable,
     n = num_procs * cfg.vertices_per_proc
     requested = num_procs * cfg.edges_per_proc
     dropped_n = int(dropped[0])
-    from repro.kernels import ops as kops
     return (EdgeList(src=u, dst=v, num_vertices=n),
             GenStats(requested_edges=requested,
                      emitted_edges=requested - dropped_n,
                      dropped_edges=dropped_n, num_vertices=n,
                      exchange_rounds=int(rounds[0]),
-                     pair_capacity=pair_capacity,
-                     fallback_counts=kops.fallback_counts()))
+                     pair_capacity=pair_capacity))
 
 
 def generate_pba_host(cfg: PBAConfig, table: FactionTable,
@@ -614,14 +610,12 @@ def generate_pba_host(cfg: PBAConfig, table: FactionTable,
     n = num_procs * cfg.vertices_per_proc
     requested = num_procs * cfg.edges_per_proc
     dropped_n = int(dropped)
-    from repro.kernels import ops as kops
     return (EdgeList(src=u, dst=v, num_vertices=n),
             GenStats(requested_edges=requested,
                      emitted_edges=requested - dropped_n,
                      dropped_edges=dropped_n, num_vertices=n,
                      exchange_rounds=int(rounds),
-                     pair_capacity=pair_capacity,
-                     fallback_counts=kops.fallback_counts()))
+                     pair_capacity=pair_capacity))
 
 
 def serial_ba_reference(num_vertices: int, k: int, seed: int = 0) -> EdgeList:

@@ -28,6 +28,7 @@ from typing import Iterator, Optional
 import numpy as np
 
 from repro.core.graph import EdgeList
+from repro.runtime import spans
 
 
 @dataclasses.dataclass
@@ -174,11 +175,13 @@ class ShardWriter:
                 f"({src.shape} vs {dst.shape})")
         if self.is_complete(i):
             return
-        n = _write_shard_file(self.out_dir, i, src, dst)
-        self.manifest["complete"].append(i)
-        self._done.add(i)
-        self.manifest["counts"][str(i)] = n
-        _dump_manifest(self.out_dir, self.manifest)
+        with spans.span("repro.sink.write", round=i,
+                        bytes=src.nbytes + dst.nbytes):
+            n = _write_shard_file(self.out_dir, i, src, dst)
+            self.manifest["complete"].append(i)
+            self._done.add(i)
+            self.manifest["counts"][str(i)] = n
+            _dump_manifest(self.out_dir, self.manifest)
 
     @property
     def edges_written(self) -> int:

@@ -48,7 +48,7 @@ from repro.core.pba import (PBAConfig, _derived_pair_capacity, _phase1,
                             pba_stream_round_block, pba_stream_setup_block,
                             stream_block_capacity)
 from repro.core.pk import PKConfig, SeedGraph, decompose_base, pk_sizes
-from repro.runtime import blocking, spmd, streaming
+from repro.runtime import blocking, spans, spmd, streaming
 from repro.runtime import topology as topology_lib
 from repro.runtime.topology import Topology
 
@@ -290,22 +290,23 @@ def _sharded_setup_fn(cfg: PBAConfig, num_procs: int, topo: Topology):
     stream, cached per (cfg, P, topology): repeated streams of one spec —
     resume legs, overlap benchmarks, shard + memory sinks of the same
     graph — reuse the jit traces instead of recompiling per instance."""
-    lp = num_procs // topo.num_devices
-    mesh = topo.build_mesh()
-    spec = topo.spec_axes
+    with spans.span("repro.build", program="setup_body"):
+        lp = num_procs // topo.num_devices
+        mesh = topo.build_mesh()
+        spec = topo.spec_axes
 
-    def setup_body(procs_blk, s_blk):
-        ranks = blocking.logical_ranks(lp, topo)
-        a, occ, recv = pba_stream_setup_block(
-            ranks, procs_blk[0], s_blk[0], cfg, num_procs, topo)
-        return a[None], occ[None], recv[None]
+        def setup_body(procs_blk, s_blk):
+            ranks = blocking.logical_ranks(lp, topo)
+            a, occ, recv = pba_stream_setup_block(
+                ranks, procs_blk[0], s_blk[0], cfg, num_procs, topo)
+            return a[None], occ[None], recv[None]
 
-    return jax.jit(spmd.shard_map(
-        setup_body, mesh=mesh,
-        in_specs=(PartitionSpec(spec, None, None),
-                  PartitionSpec(spec, None)),
-        out_specs=(PartitionSpec(spec, None, None),) * 3,
-        check_vma=False))
+        return jax.jit(spmd.shard_map(
+            setup_body, mesh=mesh,
+            in_specs=(PartitionSpec(spec, None, None),
+                      PartitionSpec(spec, None)),
+            out_specs=(PartitionSpec(spec, None, None),) * 3,
+            check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -315,34 +316,35 @@ def _sharded_grant_fns(cfg: PBAConfig, num_procs: int, topo: Topology,
     separately from setup because the urn budget is demand-derived in auto
     mode, so it is only known after setup has run. One round trace serves
     every round: the round index is a traced scalar."""
-    lp = num_procs // topo.num_devices
-    mesh = topo.build_mesh()
-    spec = topo.spec_axes
+    with spans.span("repro.build", program="pool_body,round_body"):
+        lp = num_procs // topo.num_devices
+        mesh = topo.build_mesh()
+        spec = topo.spec_axes
 
-    def pool_body():
-        ranks = blocking.logical_ranks(lp, topo)
-        pool = blocking.map_logical(
-            lambda r: _phase2_pool(r, cfg, urn_budget), ranks)
-        return pool[None]
+        def pool_body():
+            ranks = blocking.logical_ranks(lp, topo)
+            pool = blocking.map_logical(
+                lambda r: _phase2_pool(r, cfg, urn_budget), ranks)
+            return pool[None]
 
-    pool_fn = jax.jit(spmd.shard_map(
-        pool_body, mesh=mesh, in_specs=(),
-        out_specs=PartitionSpec(spec, None, None), check_vma=False))
+        pool_fn = jax.jit(spmd.shard_map(
+            pool_body, mesh=mesh, in_specs=(),
+            out_specs=PartitionSpec(spec, None, None), check_vma=False))
 
-    def round_body(r, a_blk, occ_blk, recv_blk, pool_blk):
-        ranks = blocking.logical_ranks(lp, topo)
-        u, v, counts = pba_stream_round_block(
-            r, a_blk[0], occ_blk[0], recv_blk[0], pool_blk[0], ranks,
-            cfg, num_procs, round_cap, urn_budget, block_cap, topo)
-        return u[None], v[None], counts[None]
+        def round_body(r, a_blk, occ_blk, recv_blk, pool_blk):
+            ranks = blocking.logical_ranks(lp, topo)
+            u, v, counts = pba_stream_round_block(
+                r, a_blk[0], occ_blk[0], recv_blk[0], pool_blk[0], ranks,
+                cfg, num_procs, round_cap, urn_budget, block_cap, topo)
+            return u[None], v[None], counts[None]
 
-    round_fn = jax.jit(spmd.shard_map(
-        round_body, mesh=mesh,
-        in_specs=(PartitionSpec(),)
-        + (PartitionSpec(spec, None, None),) * 4,
-        out_specs=(PartitionSpec(spec, None, None),) * 3,
-        check_vma=False))
-    return pool_fn, round_fn
+        round_fn = jax.jit(spmd.shard_map(
+            round_body, mesh=mesh,
+            in_specs=(PartitionSpec(),)
+            + (PartitionSpec(spec, None, None),) * 4,
+            out_specs=(PartitionSpec(spec, None, None),) * 3,
+            check_vma=False))
+        return pool_fn, round_fn
 
 
 class PBAShardedStream:
@@ -400,13 +402,15 @@ class PBAShardedStream:
         num_procs = self.num_procs
 
         setup = _sharded_setup_fn(cfg, num_procs, topo)
-        procs = jnp.asarray(table.procs).reshape(d, lp, table.max_s)
-        s = jnp.asarray(table.s).reshape(d, lp)
-        # Resident device state, blocked (d, lp, ...): tags, request ranks
-        # and provider-side demand never leave the mesh.
-        self._a, self._occ, self._recv = setup(procs, s)
+        with spans.span("repro.dispatch", program="setup_body"):
+            procs = jnp.asarray(table.procs).reshape(d, lp, table.max_s)
+            s = jnp.asarray(table.s).reshape(d, lp)
+            # Resident device state, blocked (d, lp, ...): tags, request
+            # ranks and provider-side demand never leave the mesh.
+            self._a, self._occ, self._recv = setup(procs, s)
 
-        recv_h = np.asarray(self._recv).reshape(num_procs, num_procs)
+        with spans.span("repro.wait"):
+            recv_h = np.asarray(self._recv).reshape(num_procs, num_procs)
         demand = recv_h.sum(axis=1, dtype=np.int64)  # per-provider total
         self.num_blocks = streaming.rounds_needed(
             max(int(recv_h.max()), 1), self.round_cap)
@@ -420,7 +424,8 @@ class PBAShardedStream:
         pool_fn, self._round = _sharded_grant_fns(
             cfg, num_procs, topo, self.urn_budget, self.round_cap,
             self.block_cap)
-        self._pool = pool_fn()
+        with spans.span("repro.dispatch", program="pool_body"):
+            self._pool = pool_fn()
 
     @property
     def exchange_rounds(self) -> int:
@@ -433,11 +438,13 @@ class PBAShardedStream:
 
     def dispatch_block(self, i: int):
         """Enqueue round ``i``'s device program; returns the in-flight
-        (u, v, counts) handle without blocking on its completion."""
+        handle, ``(i, (u, v, counts))``, without blocking on its
+        completion."""
         if not 0 <= i < self.num_blocks:
             raise ValueError(f"block {i} out of range [0, {self.num_blocks})")
-        return self._round(jnp.int32(i), self._a, self._occ, self._recv,
-                           self._pool)
+        with spans.span("repro.dispatch", program="round_body", round=i):
+            return i, self._round(jnp.int32(i), self._a, self._occ,
+                                  self._recv, self._pool)
 
     def gather_block(self, handle) -> tuple[np.ndarray, np.ndarray]:
         """Materialize a dispatched round on host and compact it: blocks
@@ -448,17 +455,26 @@ class PBAShardedStream:
         sizes (the histogram output) must equal the number of compacted
         band slots — a cheap cross-check that the fused compaction kernel
         and the gather agreed on the band."""
-        u, v, counts = handle
-        u = np.asarray(u).reshape(-1)
-        v = np.asarray(v).reshape(-1)
-        band_slots = int((u >= 0).sum())
-        counted = int(np.asarray(counts).sum())
-        if band_slots != counted:
-            raise AssertionError(
-                f"round block inconsistency: compaction kept {band_slots} "
-                f"band slots but the count kernel saw {counted}")
-        keep = (u >= 0) & (v >= 0)
-        return u[keep], v[keep]
+        i, block = handle
+        with spans.span("repro.wait", round=i):
+            jax.block_until_ready(block)
+        with spans.span("repro.sink.d2h", round=i,
+                        bytes=sum(a.nbytes for a in block)):
+            u, v, counts = (np.asarray(a) for a in block)
+        with spans.span("repro.sink.compact", round=i) as sp:
+            u = u.reshape(-1)
+            v = v.reshape(-1)
+            band_slots = int((u >= 0).sum())
+            counted = int(counts.sum())
+            if band_slots != counted:
+                raise AssertionError(
+                    f"round block inconsistency: compaction kept "
+                    f"{band_slots} band slots but the count kernel saw "
+                    f"{counted}")
+            keep = (u >= 0) & (v >= 0)
+            u, v = u[keep], v[keep]
+            sp.set_metadata(edges=len(u))
+        return u, v
 
     def block(self, i: int) -> tuple[np.ndarray, np.ndarray]:
         """Edges resolved in round ``i``: request ranks [i*C_r, (i+1)*C_r)."""
@@ -545,14 +561,12 @@ class PKStream:
 
 def stream_stats(stream, emitted: int) -> GenStats:
     """The one stats contract for a drained stream (shards or memory)."""
-    from repro.kernels import ops as kops
     return GenStats(requested_edges=stream.requested_edges,
                     emitted_edges=emitted,
                     dropped_edges=stream.requested_edges - emitted,
                     num_vertices=stream.num_vertices,
                     exchange_rounds=stream.exchange_rounds,
-                    pair_capacity=getattr(stream, "pair_capacity", 0),
-                    fallback_counts=kops.fallback_counts())
+                    pair_capacity=getattr(stream, "pair_capacity", 0))
 
 
 def stream_to_shards(stream, out_dir: str, meta: Optional[dict] = None,

@@ -41,7 +41,7 @@ class KernelEntry:
 
     ``sizes`` is a zero-arg callable (sizes may depend on derived bounds
     like edge_resolve's MAX_VMEM_ENTRIES); ``meta`` contributes static
-    facts — derived caps, fallback policy — to pallascheck's inventory.
+    facts — derived caps — to pallascheck's inventory.
     """
 
     name: str

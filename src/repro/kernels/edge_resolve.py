@@ -20,9 +20,8 @@ program's grant/consume lookups are the general case. Two regimes:
   exactly one slab, so the sum is the exact gather (no floating point,
   no scatter, every output block written exactly once — race-free under
   pallascheck's revisit rules). Valid up to ``MAX_CHUNKED_ENTRIES``
-  (= ``MAX_SLABS`` slabs, ~67M entries); past that ``ops.resolve_step`` /
-  ``ops.gather`` fall back to the jnp reference, counted per size bucket
-  in ``repro.kernels.ops.FALLBACK_EVENTS``.
+  (= ``MAX_SLABS`` slabs, ~67M entries). ``ops.resolve_step`` and
+  ``ops.gather`` run the XLA formulation at every size, not this kernel.
 
 Slab/destination-block shapes come from the analytic autotuner
 (``dispatch.autotune``) per (backend, padded size): the KC004 working set

@@ -76,18 +76,6 @@ def histogram(values: jax.Array, num_bins: int) -> jax.Array:
     return histogram_pallas(values, num_bins)
 
 
-#: Trace-time kernel-fallback counters, keyed "event:le<pow2-size-bucket>".
-#: No dispatch wrapper records an event since gather and compaction run
-#: the XLA formulation unconditionally; GenStats and pallascheck's
-#: inventory still carry the (empty) snapshot.
-FALLBACK_EVENTS: dict[str, int] = {}
-
-
-def fallback_counts() -> dict[str, int]:
-    """Snapshot of the trace-time fallback counters."""
-    return dict(FALLBACK_EVENTS)
-
-
 def resolve_step(ptr: jax.Array) -> jax.Array:
     """One ptr[ptr] pointer-doubling pass, as an XLA gather on every
     backend (Mosaic cannot gather from a multi-MiB VMEM source)."""

@@ -16,6 +16,8 @@ Submodules:
              contract: run_exchange loops (lp, P, C_r) rounds until the
              globally all-reduced residual hits zero (bounded memory,
              zero drops) — topology-agnostic by construction.
+  spans    — host spans of the generator in JAX's profiler trace, with
+             one graph id per ``api.generate`` call.
 
 No module outside ``repro.runtime`` may reference ``jax.shard_map`` /
 ``jax.experimental.shard_map``, ``jax.lax.all_to_all``, or

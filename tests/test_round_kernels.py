@@ -1,5 +1,5 @@
 """The Pallas hot path of the stream round program: dispatch, parity,
-autotuning, fallback observability, and the perf-baseline plumbing."""
+autotuning, and the perf-baseline plumbing."""
 import json
 import os
 
@@ -112,14 +112,13 @@ def test_round_program_off_mode_has_no_pallas_calls():
 
 
 def test_paper_smoke_stream_traces_without_fallback():
-    """Tracing the paper_smoke spec's device-sharded round program in
-    kernel mode records no kernel-fallback event: gather and compaction
-    are XLA by design, not by fallback."""
+    """The paper_smoke spec's device-sharded round program lowers in
+    kernel mode: gather and compaction are XLA by design, not by
+    fallback."""
     from helpers import run_with_devices
     code = """
         from repro import api
         from repro.api import GraphSpec
-        from repro.kernels import ops
         from repro.launch.bench import compile_sharded_stream_round
         pl = api.plan(GraphSpec(model="pba", procs=8,
                                 vertices_per_proc=2000, edges_per_vertex=4,
@@ -127,7 +126,6 @@ def test_paper_smoke_stream_traces_without_fallback():
         assert pl.executor == "pba_stream_sharded", pl.executor
         fn, args = compile_sharded_stream_round(pl)
         fn.lower(*args)
-        assert ops.fallback_counts() == {}, ops.fallback_counts()
         print("no-fallback")
     """
     out = run_with_devices(code, 8, {"REPRO_PALLAS": "interpret"})
@@ -199,26 +197,7 @@ def test_opcode_stats_sum_to_program_totals():
     assert all(s.optimal_seconds >= 0 for s in per_op.values())
 
 
-# --- GenStats fallback surfacing + committed bench baseline ------------------
-
-def test_genstats_surfaces_fallback_counts(monkeypatch):
-    from repro.core.graph import GenStats
-    from repro.core.stream import stream_stats
-    from repro.kernels import ops
-
-    assert GenStats(1, 1, 0, 1).fallback_counts == {}
-    monkeypatch.setattr(ops, "FALLBACK_EVENTS",
-                        {"gather_oversize:le128": 2})
-
-    class _S:
-        requested_edges, num_vertices = 10, 5
-        exchange_rounds, pair_capacity = 2, 4
-
-    st = stream_stats(_S(), 9)
-    assert st.fallback_counts == {"gather_oversize:le128": 2}
-    st.fallback_counts["x"] = 1  # snapshot, not the live dict
-    assert ops.fallback_counts() == {"gather_oversize:le128": 2}
-
+# --- committed bench baseline ------------------------------------------------
 
 def test_bench_baseline_fused_beats_jnp():
     """The committed round-program counts witness the routing: at every

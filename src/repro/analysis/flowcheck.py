@@ -804,7 +804,8 @@ class FlowProgram:
     """One traced front-door program flowcheck verifies."""
 
     label: str
-    program: str        # exchange | stream_setup | stream_round | cfree
+    program: str        # exchange | stream_setup | stream_round |
+                        # cfree_words | cfree
     topology: object
     build: Callable               # () -> (fn, example_args)
     rng_expected: bool = True
@@ -878,8 +879,9 @@ def front_door_programs(n_dev: int) -> list:
 
         # Communication-free family: same front door, zero collectives —
         # FC002 holds trivially (no all_to_all signatures to verify) and
-        # FC001 binds on the stream-words draw, whose lineage is the seed
-        # literal alone by construction.
+        # FC001 binds on the stream-words program, whose lineage is the
+        # seed literal alone by construction; the expansion program takes
+        # the words as runtime data and must draw nothing.
         for model, kw in (("ba_cfree", {"cfree_vertices": 16 * n_dev,
                                         "ba_degree": 2}),
                           ("rmat", {"cfree_vertices": 256,
@@ -889,11 +891,18 @@ def front_door_programs(n_dev: int) -> list:
             cspec = api.GraphSpec(model=model, seed=7, topology=topo,
                                   execution="sharded", **kw)
 
+            def build_words(s=cspec):
+                return bench.compile_cfree_words(api.plan(s))
+
             def build_cfree(s=cspec):
                 return bench.compile_sharded_cfree(api.plan(s))
 
-            programs.append(FlowProgram(f"{topo.label}/cfree_{model}",
-                                        "cfree", topo, build_cfree))
+            programs += [
+                FlowProgram(f"{topo.label}/cfree_words_{model}",
+                            "cfree_words", topo, build_words),
+                FlowProgram(f"{topo.label}/cfree_{model}", "cfree", topo,
+                            build_cfree, rng_expected=False),
+            ]
     for builder in _EXTRA_BUILDERS:
         programs.extend(builder(n_dev))
     return programs

@@ -37,6 +37,7 @@ instead of its destination — a principled degradation, not an error.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -285,17 +286,28 @@ def generate_cfree_host(cfg: CFreeConfig) -> tuple[EdgeList, GenStats]:
     return EdgeList(src=u, dst=v, num_vertices=n), _cfree_stats(e, n)
 
 
-def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
-    """(jitted_fn, example_args) for the sharded zero-collective program.
+@functools.lru_cache(maxsize=64)
+def words_fn(model: str, seed: int):
+    """Jitted ``() -> (4,) uint32`` stream words of ``(model, seed)``.
 
-    The one front-door cfree program: ``P = lp·D`` logical ranks each
-    expand their contiguous edge-index slice (:func:`edge_slices`) with no
-    transpose and no collective of any kind. Shared by
-    :func:`generate_cfree`, the compile-only bench harness
-    (``repro.launch.bench.compile_sharded_cfree``), and the flowcheck /
-    auditor registrations, so every layer inspects the same program. The
-    input is a per-device token that only pins the program to the mesh.
+    The draw of the sharded path: the seed is a literal of this program
+    alone, so the expansion program (:func:`sharded_expand_fn`) takes the
+    words as an argument and serves every seed of its shape. Built once
+    per (model, seed); the bound keeps a long run over many seeds from
+    holding every program it ever built.
     """
+    with spans.span("repro.build", program="cfree_words"):
+        cfg = CFreeConfig(model=model, vertices=1, seed=seed)
+        return jax.jit(lambda: cfree_words(cfg))
+
+
+@functools.lru_cache(maxsize=None)
+def _expand_program(cfg: CFreeConfig, num_procs: int, topo: Topology,
+                    kernel, mode: str):
+    # ``kernel`` and ``mode`` key the cache only: the trace bakes in the
+    # ``kops.cfree_expand`` entry point and the dispatch mode it ran under,
+    # so a program traced through one must not serve another.
+    del kernel, mode
     with spans.span("repro.build", program="cfree_expand"):
         n, e = cfree_sizes(cfg)
         d = topo.num_devices
@@ -306,9 +318,8 @@ def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
         mesh = topo.build_mesh()
         spec = topo.spec_axes
 
-        def body(tok):
+        def body(tok, words):
             del tok  # mesh token only
-            words = cfree_words(cfg)
             ranks = blocking.logical_ranks(lp, topo)
 
             def one(rank):
@@ -322,10 +333,30 @@ def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
             return u[None], v[None]
 
         fn = jax.jit(spmd.shard_map(
-            body, mesh=mesh, in_specs=(P(spec),),
+            body, mesh=mesh, in_specs=(P(spec), P()),
             out_specs=(P(spec, None, None), P(spec, None, None)),
             check_vma=False))
-        return fn, (jnp.zeros((d,), jnp.int32),)
+        return fn, jnp.zeros((d,), jnp.int32)
+
+
+def sharded_expand_fn(cfg: CFreeConfig, num_procs: int, topo: Topology):
+    """(jitted_fn, token) of the sharded zero-collective program;
+    ``fn(token, words_fn(cfg.model, cfg.seed)())`` expands the graph.
+
+    The one front-door cfree program: ``P = lp·D`` logical ranks each
+    expand their contiguous edge-index slice (:func:`edge_slices`) with no
+    transpose and no collective of any kind. Shared by
+    :func:`generate_cfree`, the compile-only bench harness
+    (``repro.launch.bench.compile_sharded_cfree``), and the flowcheck /
+    auditor registrations, so every layer inspects the same program. The
+    token is a per-device input that only pins the program to the mesh;
+    the stream words are the other input. Nothing of the seed is in the
+    program, so it is built once per shape (config without its seed, P,
+    topology) and serves every seed of it.
+    """
+    from repro.kernels import dispatch, ops as kops
+    return _expand_program(dataclasses.replace(cfg, seed=0), num_procs,
+                           topo, kops.cfree_expand, dispatch.mode())
 
 
 def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
@@ -344,9 +375,10 @@ def generate_cfree(cfg: CFreeConfig, mesh: Optional[Mesh] = None,
     topology, mesh = topology_lib.resolve(topology, mesh, axis_name)
     p = num_procs or topology.num_devices
     n, e = cfree_sizes(cfg)
-    fn, args = sharded_expand_fn(cfg, p, topology)
+    fn, tok = sharded_expand_fn(cfg, p, topology)
+    words = words_fn(cfg.model, cfg.seed)
     with spans.span("repro.dispatch", program="cfree_expand"):
-        u, v = fn(*args)
+        u, v = fn(tok, words())
     return EdgeList(src=u, dst=v, num_vertices=n), _cfree_stats(e, n)
 
 

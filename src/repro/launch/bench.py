@@ -92,7 +92,20 @@ def compile_sharded_stream_round(pl):
 def compile_sharded_cfree(pl):
     """(jitted_fn, example_args) for a communication-free plan's sharded
     expansion — the zero-collective front-door program the auditor pins
-    to exactly 0 all_to_alls (core.cfree.sharded_expand_fn)."""
+    to exactly 0 all_to_alls (core.cfree.sharded_expand_fn). The args are
+    the mesh token and the plan's stream words, drawn by the words
+    program (:func:`compile_cfree_words`)."""
     from repro.core import cfree
 
-    return cfree.sharded_expand_fn(pl.config, pl.num_procs, pl.topology)
+    fn, tok = cfree.sharded_expand_fn(pl.config, pl.num_procs, pl.topology)
+    words, _ = compile_cfree_words(pl)
+    return fn, (tok, words())
+
+
+def compile_cfree_words(pl):
+    """(jitted_fn, example_args) for a communication-free plan's stream
+    words program — the one program of the path that draws randomness,
+    with the seed as its literal (core.cfree.words_fn)."""
+    from repro.core import cfree
+
+    return cfree.words_fn(pl.config.model, pl.config.seed), ()

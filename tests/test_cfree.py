@@ -77,6 +77,54 @@ def test_p1_vs_p8_bit_identical(model, kw):
         assert np.array_equal(hd, sd), (model, procs)
 
 
+# --- one expansion program per shape ------------------------------------------
+
+def _builds(monkeypatch) -> list:
+    """The ``program`` of every ``repro.build`` span opened from now on."""
+    from repro.runtime import spans
+    real, built = spans.span, []
+
+    def span(name, **args):
+        if name == "repro.build":
+            built.append(args["program"])
+        return real(name, **args)
+    monkeypatch.setattr(spans, "span", span)
+    cfree_lib._expand_program.cache_clear()
+    cfree_lib.words_fn.cache_clear()
+    return built
+
+
+@pytest.mark.parametrize("model,kw", MODELS, ids=[m for m, _ in MODELS])
+def test_sharded_program_built_once_per_spec(model, kw, monkeypatch):
+    """The first graph of a spec builds its words and expansion programs;
+    the second finds both cached and gives the same edges."""
+    built = _builds(monkeypatch)
+    spec = _spec(model, kw, execution="sharded", procs=4)
+    first = api.generate(spec).edges.to_numpy()
+    assert built == ["cfree_expand", "cfree_words"]
+    second = api.generate(spec).edges.to_numpy()
+    assert built == ["cfree_expand", "cfree_words"]
+    for a, b in zip(first, second):
+        assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("model,kw", MODELS, ids=[m for m, _ in MODELS])
+def test_new_seed_builds_only_its_words(model, kw, monkeypatch):
+    """The seed lives in the words program alone: two seeds of one shape
+    share one expansion program, and each gives the host path's edges for
+    its own seed, which differ between the seeds."""
+    built = _builds(monkeypatch)
+    out = {}
+    for seed in (11, 2**31 + 12345):
+        spec = _spec(model, kw, execution="sharded", procs=4, seed=seed)
+        out[seed] = api.generate(spec).edges.to_numpy()
+        host, _ = cfree_lib.generate_cfree_host(api.plan(spec).config)
+        assert np.array_equal(out[seed][0], np.asarray(host.src)), seed
+        assert np.array_equal(out[seed][1], np.asarray(host.dst)), seed
+    assert built == ["cfree_expand", "cfree_words", "cfree_words"]
+    assert not np.array_equal(out[11][1], out[2**31 + 12345][1])
+
+
 # --- slab-boundary independence -----------------------------------------------
 
 @pytest.mark.parametrize("model,kw", MODELS, ids=[m for m, _ in MODELS])

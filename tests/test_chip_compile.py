@@ -11,6 +11,7 @@ The last tests run ``chip_smoke.py`` itself on the CPU, where it must
 refuse to run.
 """
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -104,6 +105,48 @@ def test_kernel_compiles_for_v5e(topo, name):
         compiled = fn.lower(*[_sds(s, d, one_chip) for s, d in shapes]
                             ).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_cfree_program_keeps_the_kernel_signature(topo, monkeypatch):
+    """The sharded R-MAT expansion as the front door builds it (scale 20,
+    edgefactor 16), compiled for a described chip: the stream words, now
+    an argument of the program, still reach the kernel as its (4,) uint32
+    operand beside the (rows, 128) int32 edge indices, the custom call
+    that ``cfree_expand_roofline`` reads."""
+    monkeypatch.syspath_prepend(REPO)
+    from bench import harness
+    from repro.launch import bench as launch_bench
+    pl = api.plan(GraphSpec(model="rmat", cfree_vertices=1 << 20,
+                            cfree_edges=16 << 20, seed=7,
+                            execution="sharded", topology=Topology.flat(1)))
+    build_mesh = Topology.build_mesh
+    monkeypatch.setattr(Topology, "build_mesh",
+                        lambda self, devices=None: build_mesh(self,
+                                                              topo.devices))
+    cfree_lib._expand_program.cache_clear()
+    try:
+        with dispatch.forced_mode("tpu"):
+            fn, (tok, words) = launch_bench.compile_sharded_cfree(pl)
+            mesh = pl.topology.build_mesh()
+            compiled = fn.lower(
+                _sds(tok.shape, tok.dtype,
+                     NamedSharding(mesh, P(pl.topology.spec_axes))),
+                _sds(words.shape, words.dtype, NamedSharding(mesh, P()))
+            ).compile()
+    finally:
+        cfree_lib._expand_program.cache_clear()
+    roofline = harness.load_module(
+        os.path.join(REPO, "bench", "metrics", "cfree_expand_roofline.py"),
+        "cfree_expand_roofline")
+    # The profiler names an operation by its HLO with each operand's
+    # shape before the operand; as_text() gives the names alone.
+    hlo = compiled.as_text()
+    shapes = dict(re.findall(r"(%\S+) = (\S+) ", hlo))
+    typed = re.sub(r"%[\w.\-]+(?=[,)])",
+                   lambda m: f"{shapes.get(m.group(), '?')} {m.group()}", hlo)
+    assert (words.shape, words.dtype) == ((4,), jnp.uint32)
+    assert len(re.findall(roofline.KERNEL, typed)) == 1, hlo[-3000:]
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 

@@ -70,6 +70,24 @@ def test_exchange_traces_rng_and_collectives(flow_run):
     assert not rnd["rng_prims"], "stream round must not redraw"
 
 
+def test_cfree_draw_lives_in_the_words_program(flow_run):
+    """Each communication-free model registers its stream-words program
+    as the draw FC001 checks, and its expansion program as one that must
+    draw nothing: the words reach it as its second input."""
+    _, inv = flow_run
+    registered = {p.label: p for p in fc.front_door_programs(1)}
+    for model in ("ba_cfree", "rmat", "er"):
+        words = registered[f"flat_1x1/cfree_words_{model}"]
+        expand = registered[f"flat_1x1/cfree_{model}"]
+        assert (words.program, words.rng_expected) == ("cfree_words", True)
+        assert (expand.program, expand.rng_expected) == ("cfree", False)
+        w = inv["programs"][f"flat_1x1/cfree_words_{model}"]
+        x = inv["programs"][f"flat_1x1/cfree_{model}"]
+        assert w["ok"] and w["rng_prims"].get("random_bits")
+        assert x["ok"] and not x["rng_prims"]
+        assert (w["invars"], x["invars"]) == (0, 2)
+
+
 def test_verified_transposes_cover_both_entry_points(flow_run):
     _, inv = flow_run
     for topo_label, entries in inv["transposes"].items():

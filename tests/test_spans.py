@@ -13,6 +13,7 @@ import pytest
 
 from repro import api
 from repro.api import GraphSpec, Topology
+from repro.core import cfree as cfree_lib
 from repro.core import stream as stream_lib
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
@@ -41,6 +42,8 @@ def recorded(tmp_path_factory):
     span; then the same PBA and R-MAT graphs with the profiler off."""
     stream_lib._sharded_setup_fn.cache_clear()
     stream_lib._sharded_grant_fns.cache_clear()
+    cfree_lib._expand_program.cache_clear()
+    cfree_lib.words_fn.cache_clear()
     trace_dir = str(tmp_path_factory.mktemp("trace"))
     shards = PBA.replace(sink="shards",
                          out_dir=str(tmp_path_factory.mktemp("shards")))
@@ -108,10 +111,10 @@ def test_one_wait_copy_and_compaction_per_round(recorded, k):
 
 def test_programs_built_per_graph(recorded):
     """PBA builds its set-up and grant programs on the first graph of a
-    spec and finds them cached after; R-MAT builds its expansion program
-    on every graph."""
+    spec and finds them cached after; R-MAT builds its words and expansion
+    programs on the first graph and finds them cached after."""
     built = [len(_spans_of(recorded, k, "repro.build")) for k in range(4)]
-    assert built == [2, 0, 1, 1]
+    assert built == [2, 0, 2, 0]
 
 
 def _stats_by_span(path):

@@ -10,6 +10,7 @@ tests and only the worker running this file loads the TPU library.
 The last tests run ``chip_smoke.py`` itself on the CPU, where it must
 refuse to run.
 """
+import json
 import os
 import re
 import shutil
@@ -150,12 +151,12 @@ def test_cfree_program_keeps_the_kernel_signature(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-def _round_program(devices, topology: Topology):
-    """The streamed PBA round program of PBA_SPEC on ``devices``, with
-    ShapeDtypeStruct arguments sharded over them (built from
-    pba_stream_round_block directly: api.plan checks the present device
-    count)."""
-    pl = api.plan(PBA_SPEC)
+def _round_program(devices, topology: Topology, spec: GraphSpec = PBA_SPEC):
+    """The streamed PBA round program of ``spec`` (planned on one device)
+    on ``devices``, with ShapeDtypeStruct arguments sharded over them
+    (built from pba_stream_round_block directly: api.plan checks the
+    present device count)."""
+    pl = api.plan(spec)
     cfg, p = pl.config, pl.num_procs
     e, c_r, urn = cfg.edges_per_proc, pl.round_capacity, pl.urn_budget
     d = topology.num_devices
@@ -195,6 +196,33 @@ def test_one_chip_round_program_compiles_and_fits(topo):
 
 def test_four_chip_round_program_compiles(topo):
     compiled = _round_program(topo.devices, Topology.flat(4))
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 1
+    assert "all-to-all" in hlo
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def _weak4_spec() -> GraphSpec:
+    """The ``pba_table1.weak4`` benchmark cell's spec, read from its
+    configuration: 256 ranks of 20,000 vertices x 5 edges, R=8, urns of
+    2E."""
+    with open(os.path.join(REPO, "bench", "configs",
+                           "pba_table1_weak4.json")) as f:
+        spec = json.load(f)["spec"]
+    return GraphSpec(**spec, seed=7, execution="streamed",
+                     topology=Topology.flat(1))
+
+
+def test_weak4_round_program_compiles_and_fits(topo):
+    """The round program at the four-chip cell's shapes: lp=64 of P=256
+    ranks per chip, E=100,000 local edges, the derived round capacity
+    C_r=59, urns of 2E, an all_to_all across flat(4)."""
+    pl = api.plan(_weak4_spec())
+    assert (pl.num_procs, pl.config.edges_per_proc) == (256, 100_000)
+    assert (pl.pair_capacity, pl.round_capacity) == (469, 59)
+    assert pl.urn_budget == 200_000
+    assert Topology.flat(4).lp(pl.num_procs) == 64
+    compiled = _round_program(topo.devices, Topology.flat(4), pl.spec)
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 1
     assert "all-to-all" in hlo

@@ -119,15 +119,26 @@ def default_pair_capacity(edges_per_proc: int, min_s: int,
     c = 8 * edges_per_proc // max(min_s, 1)
     c = int(min(max(c, 64), edges_per_proc))
     if num_procs:
-        mem = (memory_bytes if memory_bytes is not None
-               else spmd.device_memory_bytes())
-        budget = max(mem // _EXCHANGE_MEM_DIVISOR, 1)
-        rounds = max(exchange_rounds or 1, 1)
-        cap = (budget // (4 * num_procs)) * rounds
-        if exchange_rounds is not None:
-            cap = max(cap, _MIN_ROUND_CAPACITY * rounds)
+        cap = exchange_memory_cap(num_procs, exchange_rounds, memory_bytes)
         c = int(max(min(c, cap), 1))
     return c
+
+
+def exchange_memory_cap(num_procs: int,
+                        exchange_rounds: Optional[int] = None,
+                        memory_bytes: Optional[int] = None) -> int:
+    """The largest total pair capacity C whose per-logical-processor
+    (P, C_r) int32 round buffer fits 1/16 of device memory (floored at
+    C_r = 16 when rounds are streamed): the upper bound of
+    :func:`default_pair_capacity` and of the streams' demand-sized C."""
+    mem = (memory_bytes if memory_bytes is not None
+           else spmd.device_memory_bytes())
+    budget = max(mem // _EXCHANGE_MEM_DIVISOR, 1)
+    rounds = max(exchange_rounds or 1, 1)
+    cap = (budget // (4 * num_procs)) * rounds
+    if exchange_rounds is not None:
+        cap = max(cap, _MIN_ROUND_CAPACITY * rounds)
+    return cap
 
 
 def resolve_pointers(ptr: jax.Array, terminal: jax.Array,
@@ -401,13 +412,13 @@ def pba_stream_round_block(r, a, occ, recv_counts, pool, ranks,
     the same permutation of the same values), and the per-provider band
     counts come from the histogram kernel. Band edges move to the front
     in edge order (request ranks are unique per pair, so compaction is
-    collision-free), and only the leading ``block_cap = min(E, P*C_r)``
-    columns — a static bound on any round's band size — return to the
-    host. Returns (u, v, counts): u, v of shape (lp, block_cap) with -1
-    marking padding (and, in ``v``, urn-exhausted grants, which the host
-    drops exactly like the host-path stream), and counts (lp, P) — this
-    round's per-provider band sizes, the host-side consistency check on
-    the compacted block.
+    collision-free), and only the leading ``block_cap`` columns — a
+    static bound on any round's band size, at most ``min(E, P*C_r)`` —
+    return to the host. Returns (u, v, counts): u, v of shape
+    (lp, block_cap) with -1 marking padding (and, in ``v``, urn-exhausted
+    grants, which the host drops exactly like the host-path stream), and
+    counts (lp, P) — this round's per-provider band sizes, the host-side
+    consistency check on the compacted block.
     """
     from repro.kernels import ops as kops
     lp = a.shape[0]

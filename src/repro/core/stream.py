@@ -44,9 +44,9 @@ from repro.core import storage
 from repro.core.factions import FactionTable, validate_table
 from repro.core.graph import GenStats
 from repro.core.pba import (PBAConfig, _derived_pair_capacity, _phase1,
-                            _phase2_pool, occurrence_rank,
-                            pba_stream_round_block, pba_stream_setup_block,
-                            stream_block_capacity)
+                            _phase2_pool, exchange_memory_cap,
+                            occurrence_rank, pba_stream_round_block,
+                            pba_stream_setup_block, stream_block_capacity)
 from repro.core.pk import PKConfig, SeedGraph, decompose_base, pk_sizes
 from repro.runtime import blocking, spans, spmd, streaming
 from repro.runtime import topology as topology_lib
@@ -64,6 +64,55 @@ class EdgeBlock:
 
 def _next_pow2(n: int) -> int:
     return 1 << max(int(n) - 1, 0).bit_length()
+
+
+#: Columns the round block's width is rounded up to: seeds whose largest
+#: bands differ by less share one program shape and one device peak.
+BLOCK_GRANULE = 2048
+
+
+@dataclasses.dataclass(frozen=True)
+class RoundShape:
+    """A PBA stream's static round shapes."""
+
+    pair_capacity: int   # total per-pair capacity C
+    round_cap: int       # C_r: request ranks a pair ships per round
+    num_blocks: int      # rounds the busiest pair needs at C_r
+    block_cap: int       # round 0's largest band, rounded up
+    demand_sized: bool   # the busiest pair's demand raised C
+
+
+def stream_round_shape(cfg: PBAConfig, table: FactionTable,
+                       demand: np.ndarray) -> RoundShape:
+    """Size a stream's rounds from ``demand``, the (requester, provider)
+    endpoint counts the set-up measured.
+
+    Unless ``cfg.pair_capacity`` pins it, C is the larger of the
+    faction-size heuristic and the busiest pair's demand (at most
+    :func:`~repro.core.pba.exchange_memory_cap`), so that pair is served
+    within the configured R rounds and no stream runs more rounds than
+    the heuristic alone gives. C_r only decides which round carries an
+    edge: an endpoint's pool slot is fixed by the pair's offset and the
+    request rank, whatever C_r is. The block capacity is the largest band
+    of round 0, max_i sum_q min(c_iq, C_r) (no later round's is wider),
+    rounded up to :data:`BLOCK_GRANULE` columns and at most min(E, P*C_r),
+    a bound every band keeps. The shapes are a pure function of (cfg,
+    table), as the demand is, so both stream drivers derive the same ones.
+    """
+    heuristic = _derived_pair_capacity(cfg, table)
+    busiest = max(int(demand.max()), 1)
+    c = heuristic
+    if not cfg.pair_capacity:
+        c = max(heuristic, min(busiest, exchange_memory_cap(
+            table.num_procs, cfg.exchange_rounds)))
+    round_cap = streaming.round_capacity(c, cfg.exchange_rounds or 1)
+    band = int(np.minimum(demand, round_cap).sum(axis=1).max())
+    block_cap = min(-(-max(band, 1) // BLOCK_GRANULE) * BLOCK_GRANULE,
+                    stream_block_capacity(cfg.edges_per_proc,
+                                          table.num_procs, round_cap))
+    return RoundShape(c, round_cap,
+                      streaming.rounds_needed(busiest, round_cap),
+                      block_cap, c > heuristic)
 
 
 def stream_urn_budget(cfg: PBAConfig, max_demand: int,
@@ -179,12 +228,6 @@ class PBAStream:
         self.num_procs = table.num_procs
         self.num_vertices = self.num_procs * cfg.vertices_per_proc
         self.requested_edges = self.num_procs * cfg.edges_per_proc
-        # Same derivation as the on-device generators, so parity mode
-        # reproduces generate_pba_host at the identical budget.
-        pair_capacity = _derived_pair_capacity(cfg, table)
-        self.pair_capacity = pair_capacity
-        self.round_cap = streaming.round_capacity(
-            pair_capacity, cfg.exchange_rounds or 1)
 
         cfg_ = cfg
         num_procs = self.num_procs
@@ -204,8 +247,10 @@ class PBAStream:
         self._a = np.asarray(a)
         self._occ = np.asarray(occ)
         counts_h = np.asarray(counts)          # (requester, provider)
-        self.num_blocks = streaming.rounds_needed(
-            max(int(counts_h.max()), 1), self.round_cap)
+        self.shape = stream_round_shape(cfg, table, counts_h)
+        self.pair_capacity = self.shape.pair_capacity
+        self.round_cap = self.shape.round_cap
+        self.num_blocks = self.shape.num_blocks
 
         demand = counts_h.sum(axis=0, dtype=np.int64)  # per-provider total
         self.urn_budget = stream_urn_budget(cfg, int(demand.max()),
@@ -311,12 +356,17 @@ def _sharded_setup_fn(cfg: PBAConfig, num_procs: int, topo: Topology):
 
 @functools.lru_cache(maxsize=None)
 def _sharded_grant_fns(cfg: PBAConfig, num_procs: int, topo: Topology,
-                       urn_budget: int, round_cap: int, block_cap: int):
+                       urn_budget: int, round_cap: int, block_cap: int,
+                       demand_sized: bool = False):
     """Compiled SPMD (pool, round) programs for a sharded stream — keyed
-    separately from setup because the urn budget is demand-derived in auto
-    mode, so it is only known after setup has run. One round trace serves
-    every round: the round index is a traced scalar."""
-    with spans.span("repro.build", program="pool_body,round_body"):
+    separately from setup because the urn budget and the round shapes are
+    demand-derived, so they are only known after setup has run. One round
+    trace serves every round: the round index is a traced scalar.
+    ``demand_sized`` (the set-up's demand raised C) is recorded on the
+    build span only."""
+    with spans.span("repro.build", program="pool_body,round_body",
+                    round_cap=round_cap, block_cap=block_cap,
+                    demand_sized=int(demand_sized)):
         lp = num_procs // topo.num_devices
         mesh = topo.build_mesh()
         spec = topo.spec_axes
@@ -361,13 +411,15 @@ class PBAShardedStream:
     topology's blocked transpose (flat all_to_all, or the hierarchical
     two-hop on ``Topology.pods`` — streaming rides the 2-D-mesh transpose
     with no new exchange code), and only the compacted per-round edge
-    block — (P, min(E, P*C_r)) ints — is gathered back to the host for the
-    shard writer. Per-device memory is O(lp * (E + urn budget + P*C_r)),
-    independent of the round count; the graph has to fit on disk only.
+    block — (P, block_cap) ints, the largest band a round carries — is
+    gathered back to the host for the shard writer. Per-device memory is
+    O(lp * (E + urn budget + P*C_r)), independent of the round count; the
+    graph has to fit on disk only.
 
     Bit-parity: blocks are bit-identical to :class:`PBAStream` for the
     same (cfg, table, auto_capacity) on every topology — both streams
-    derive the same round windows, draw pools at the same uniform
+    derive the same round windows from the same demand
+    (:func:`stream_round_shape`), draw pools at the same uniform
     :func:`stream_urn_budget`, and address the same slots — so manifests
     written by either driver resume under the other, and parity mode
     (``auto_capacity=False``) reproduces ``generate_pba_host``'s edge
@@ -389,10 +441,6 @@ class PBAShardedStream:
         self.num_procs = table.num_procs
         self.num_vertices = self.num_procs * cfg.vertices_per_proc
         self.requested_edges = self.num_procs * cfg.edges_per_proc
-        pair_capacity = _derived_pair_capacity(cfg, table)
-        self.pair_capacity = pair_capacity
-        self.round_cap = streaming.round_capacity(
-            pair_capacity, cfg.exchange_rounds or 1)
 
         topo, _ = topology_lib.resolve(topology, None)
         self.topology = topo
@@ -412,18 +460,18 @@ class PBAShardedStream:
         with spans.span("repro.wait"):
             recv_h = np.asarray(self._recv).reshape(num_procs, num_procs)
         demand = recv_h.sum(axis=1, dtype=np.int64)  # per-provider total
-        self.num_blocks = streaming.rounds_needed(
-            max(int(recv_h.max()), 1), self.round_cap)
+        self.shape = stream_round_shape(cfg, table, recv_h.T)
+        self.pair_capacity = self.shape.pair_capacity
+        self.round_cap = self.shape.round_cap
+        self.num_blocks = self.shape.num_blocks
         self.urn_budget = stream_urn_budget(cfg, int(demand.max()),
                                             auto_capacity)
         if auto_capacity:
             _warn_skewed_budget(cfg, self.urn_budget, float(demand.mean()),
                                 lp)
-        self.block_cap = stream_block_capacity(cfg.edges_per_proc,
-                                               num_procs, self.round_cap)
         pool_fn, self._round = _sharded_grant_fns(
             cfg, num_procs, topo, self.urn_budget, self.round_cap,
-            self.block_cap)
+            self.shape.block_cap, self.shape.demand_sized)
         with spans.span("repro.dispatch", program="pool_body"):
             self._pool = pool_fn()
 

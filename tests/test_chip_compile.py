@@ -28,7 +28,7 @@ from repro.api import GraphSpec, Topology
 from repro.core import cfree as cfree_lib
 from repro.core.pba import pba_stream_round_block, stream_block_capacity
 from repro.kernels import dispatch
-from repro.runtime import blocking, spmd
+from repro.runtime import blocking, spmd, streaming
 
 #: One v5e chip's HBM (Google Cloud, "TPU v5e").
 V5E_HBM_BYTES = 16 * 10**9
@@ -151,14 +151,18 @@ def test_cfree_program_keeps_the_kernel_signature(topo, monkeypatch):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-def _round_program(devices, topology: Topology, spec: GraphSpec = PBA_SPEC):
+def _round_program(devices, topology: Topology, spec: GraphSpec = PBA_SPEC,
+                   round_cap: int = 0):
     """The streamed PBA round program of ``spec`` (planned on one device)
     on ``devices``, with ShapeDtypeStruct arguments sharded over them
     (built from pba_stream_round_block directly: api.plan checks the
-    present device count)."""
+    present device count). ``round_cap`` replaces the plan's C_r, a floor
+    of the demand-sized one; the block takes the largest band that C_r
+    allows, min(E, P*C_r)."""
     pl = api.plan(spec)
     cfg, p = pl.config, pl.num_procs
-    e, c_r, urn = cfg.edges_per_proc, pl.round_capacity, pl.urn_budget
+    e, urn = cfg.edges_per_proc, pl.urn_budget
+    c_r = round_cap or pl.round_capacity
     d = topology.num_devices
     lp = topology.lp(p)
     block_cap = stream_block_capacity(e, p, c_r)
@@ -213,16 +217,27 @@ def _weak4_spec() -> GraphSpec:
                      topology=Topology.flat(1))
 
 
+#: The busiest (requester, provider) pair's demand at the four-chip cell's
+#: shape, the largest over 32 seeds (PERF.md §6).
+WEAK4_BUSIEST_PAIR = 1_933
+
+
 def test_weak4_round_program_compiles_and_fits(topo):
     """The round program at the four-chip cell's shapes: lp=64 of P=256
-    ranks per chip, E=100,000 local edges, the derived round capacity
-    C_r=59, urns of 2E, an all_to_all across flat(4)."""
+    ranks per chip, E=100,000 local edges, urns of 2E, an all_to_all
+    across flat(4), and the round capacity the stream derives from the
+    busiest pair's demand, C_r = ceil(1,933 / 8) = 242 (the plan's
+    heuristic floor is C=469, C_r=59), with a block of the widest band it
+    allows."""
     pl = api.plan(_weak4_spec())
     assert (pl.num_procs, pl.config.edges_per_proc) == (256, 100_000)
     assert (pl.pair_capacity, pl.round_capacity) == (469, 59)
     assert pl.urn_budget == 200_000
     assert Topology.flat(4).lp(pl.num_procs) == 64
-    compiled = _round_program(topo.devices, Topology.flat(4), pl.spec)
+    c_r = streaming.round_capacity(WEAK4_BUSIEST_PAIR, 8)
+    assert c_r == 242
+    compiled = _round_program(topo.devices, Topology.flat(4), pl.spec,
+                              round_cap=c_r)
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 1
     assert "all-to-all" in hlo

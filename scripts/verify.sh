@@ -45,25 +45,29 @@ echo "== 8-host-device smoke =="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" python - <<'PY'
 import numpy as np
 from repro.core import (FactionSpec, PBAConfig, PKConfig, make_factions,
-                        generate_pba, generate_pba_host, generate_pk,
-                        star_clique_seed)
+                        generate_pba, generate_pk, star_clique_seed)
 from repro.core.distributed_analysis import (degree_counts_sharded,
                                              edge_count_sharded)
+from repro.runtime import Topology
 
 table = make_factions(8, FactionSpec(4, 2, 4, seed=1))
 cfg = PBAConfig(vertices_per_proc=200, edges_per_vertex=3, seed=7)
-e_d, st_d = generate_pba(cfg, table)
-e_h, st_h = generate_pba_host(cfg, table)
-np.testing.assert_array_equal(np.asarray(e_d.src), np.asarray(e_h.src))
-np.testing.assert_array_equal(np.asarray(e_d.dst), np.asarray(e_h.dst))
+e_d, st_d = generate_pba(cfg, table, Topology.flat(8))
+e_h, st_h = generate_pba(cfg, table, Topology.host())
+np.testing.assert_array_equal(np.asarray(e_d.src).reshape(-1),
+                              np.asarray(e_h.src).reshape(-1))
+np.testing.assert_array_equal(np.asarray(e_d.dst).reshape(-1),
+                              np.asarray(e_h.dst).reshape(-1))
 
 # multi-round streaming exchange: same parity contract, zero drops
 import dataclasses
 cfg_s = dataclasses.replace(cfg, pair_capacity=8, exchange_rounds=4)
-e_ds, st_ds = generate_pba(cfg_s, table)
-e_hs, st_hs = generate_pba_host(cfg_s, table)
-np.testing.assert_array_equal(np.asarray(e_ds.src), np.asarray(e_hs.src))
-np.testing.assert_array_equal(np.asarray(e_ds.dst), np.asarray(e_hs.dst))
+e_ds, st_ds = generate_pba(cfg_s, table, Topology.flat(8))
+e_hs, st_hs = generate_pba(cfg_s, table, Topology.host())
+np.testing.assert_array_equal(np.asarray(e_ds.src).reshape(-1),
+                              np.asarray(e_hs.src).reshape(-1))
+np.testing.assert_array_equal(np.asarray(e_ds.dst).reshape(-1),
+                              np.asarray(e_hs.dst).reshape(-1))
 assert st_ds.exchange_rounds == st_hs.exchange_rounds > 1, (st_ds, st_hs)
 
 pk_edges, pk_st = generate_pk(star_clique_seed(4), PKConfig(levels=5))
@@ -79,8 +83,7 @@ echo "== 2x4 hierarchical smoke =="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" python - <<'PY'
 import dataclasses
 import numpy as np
-from repro.core import PBAConfig, generate_pba_host, hub_factions
-from repro.core.pba import generate_pba_sharded
+from repro.core import PBAConfig, generate_pba, hub_factions
 from repro.runtime import Topology
 
 # Two-hop intra-pod/cross-pod exchange must be bit-identical to the host
@@ -89,9 +92,9 @@ table = hub_factions(8)
 cfg = PBAConfig(vertices_per_proc=150, edges_per_vertex=3, seed=5,
                 pair_capacity=16, total_capacity_factor=8)
 for cfg_i in (cfg, dataclasses.replace(cfg, exchange_rounds=4)):
-    e_h, st_h = generate_pba_host(cfg_i, table)
+    e_h, st_h = generate_pba(cfg_i, table, Topology.host())
     for topo in (Topology.pods(2, 4), Topology.pods(4, 2)):
-        e_s, st_s = generate_pba_sharded(cfg_i, table, topology=topo)
+        e_s, st_s = generate_pba(cfg_i, table, topology=topo)
         np.testing.assert_array_equal(np.asarray(e_s.src).reshape(-1),
                                       np.asarray(e_h.src).reshape(-1))
         np.testing.assert_array_equal(np.asarray(e_s.dst).reshape(-1),

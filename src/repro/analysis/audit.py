@@ -414,8 +414,7 @@ def audit_plan(pl, with_hlo: bool = True) -> list:
 
     Host-execution plans have no SPMD program and audit to an empty list.
     """
-    if pl.topology.is_host or pl.executor in ("pba_host", "pk_host",
-                                              "pba_stream_host"):
+    if pl.topology.is_host:
         return []
     from repro.core.spec import CFREE_MODELS
     if pl.model in CFREE_MODELS:

@@ -137,9 +137,8 @@ class FrontDoorRule(BannedPathRule):
     id = "RPR003"
     title = "legacy generator entry points outside src/"
     include = ("examples", "benchmarks", "scripts")
-    LEGACY = frozenset({"generate_pba_sharded", "generate_pba_host",
-                        "generate_pk_host", "PBAStream", "PKStream",
-                        "stream_to_shards"})
+    LEGACY = frozenset({"generate_pba", "generate_pk_host", "PBAStream",
+                        "PKStream", "stream_to_shards"})
 
     def banned(self, path: str) -> Optional[str]:
         parts = path.split(".")

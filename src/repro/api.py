@@ -274,15 +274,13 @@ def _plan_pba(spec: GraphSpec) -> GenPlan:
     execution = _resolve_execution(
         spec, divisible=p % max(spmd.device_count(), 1) == 0
         if spec.topology is None else True)
+    executor = "generate_pba"
     if execution == "sharded":
         topo, lp = _device_topology(spec, p)
-        executor = ("generate_pba" if lp == 1 and topo.num_devices == p
-                    else "generate_pba_sharded")
     elif execution == "streamed":
         topo, lp, executor = _streamed_pba_topology(spec, p)
     else:
         topo, lp = Topology.host(), p
-        executor = "generate_pba_host"
 
     pair_capacity = pba_lib._derived_pair_capacity(cfg, table)
     rounds = cfg.exchange_rounds or 1
@@ -546,14 +544,8 @@ def _execute(pl: GenPlan) -> GenResult:
         return GenResult(plan=pl, stats=stats, edges=edges)
 
     if pl.model == "pba":
-        if pl.execution == "host":
-            edges, stats = pba_lib.generate_pba_host(pl.config, pl.table)
-        elif pl.executor == "generate_pba":
-            edges, stats = pba_lib.generate_pba(pl.config, pl.table,
-                                                topology=pl.topology)
-        else:
-            edges, stats = pba_lib.generate_pba_sharded(
-                pl.config, pl.table, topology=pl.topology)
+        edges, stats = pba_lib.generate_pba(pl.config, pl.table,
+                                            pl.topology)
     elif pl.model == "pk":
         if pl.execution == "host":
             edges, stats = pk_lib.generate_pk_host(pl.seed_graph, pl.config)

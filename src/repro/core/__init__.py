@@ -3,13 +3,13 @@
 Public API — the **one front door** is ``repro.api``:
   GraphSpec -> repro.api.plan() -> repro.api.generate()
 
-The per-model entry points below (generate_pba*, generate_pk*, PBAStream,
+The per-model entry points below (generate_pba, generate_pk*, PBAStream,
 PKStream, stream_to_shards) are the internal executors that front door
 dispatches to. They remain importable for compatibility but are
 deprecated as public entry points — new callers should build a GraphSpec
 (see README "One front door").
 
-  PBA (parallel Barabási–Albert): PBAConfig, generate_pba, generate_pba_host
+  PBA (parallel Barabási–Albert): PBAConfig, generate_pba
   PK (parallel Kronecker): PKConfig, SeedGraph, generate_pk, generate_pk_host
   Factions: FactionSpec, FactionTable, make_factions, block_factions
   Out-of-core streaming: PBAStream, PKStream, stream_to_shards
@@ -45,8 +45,6 @@ from repro.core.analysis import (fit_power_law, sampled_path_stats,
 # door (plan/generate) instead.
 _DEPRECATED_ENTRY_POINTS = {
     "generate_pba": _pba.generate_pba,
-    "generate_pba_host": _pba.generate_pba_host,
-    "generate_pba_sharded": _pba.generate_pba_sharded,
     "generate_pk": _pk.generate_pk,
     "generate_pk_host": _pk.generate_pk_host,
     "PBAStream": _stream.PBAStream,
@@ -72,8 +70,7 @@ __all__ = [
     "FactionSpec", "FactionTable", "make_factions", "block_factions",
     "hub_factions",
     "GraphSpec", "spec_digest",
-    "PBAConfig", "generate_pba", "generate_pba_host", "generate_pba_sharded",
-    "serial_ba_reference",
+    "PBAConfig", "generate_pba", "serial_ba_reference",
     "PKConfig", "SeedGraph", "generate_pk", "generate_pk_host",
     "star_clique_seed", "dense_power_seed", "dense_kronecker_power",
     "pk_sizes", "xor_randomize",

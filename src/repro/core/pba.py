@@ -17,7 +17,8 @@ Faithful JAX/TPU re-derivation of the paper's MPI algorithm (DESIGN.md §2):
                     gather).
 
 Everything is deterministic given (seed, P): all randomness is counter-based
-and keyed by (seed, stream, rank).
+and keyed by (seed, stream, rank). One in-memory program, :func:`generate_pba`,
+serves every topology, the host included; core/stream.py drives the rest.
 """
 from __future__ import annotations
 
@@ -27,13 +28,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.core import rng as rng_lib
 from repro.core.factions import FactionTable, validate_table
 from repro.core.graph import EdgeList, GenStats
 from repro.runtime import blocking, spmd, streaming
-from repro.runtime import topology as topology_lib
 from repro.runtime.topology import Topology
 
 
@@ -450,21 +450,6 @@ def stream_block_capacity(edges_per_proc: int, num_procs: int,
     return min(edges_per_proc, num_procs * round_cap)
 
 
-def pba_shard_body(rank, faction_row, s, cfg: PBAConfig, num_procs: int,
-                   pair_capacity: int, topo: Topology):
-    """Per-device PBA program (one logical proc per device).
-
-    ``Topology.host()`` => single-device (P must be 1). Thin lp=1 wrapper
-    over :func:`pba_logical_block`.
-    """
-    ranks = jnp.reshape(jnp.asarray(rank, jnp.int32), (1,))
-    s_blk = jnp.reshape(jnp.asarray(s, jnp.int32), (1,))
-    u, v, dropped, granted, _ = pba_logical_block(
-        ranks, faction_row[None], s_blk, cfg, num_procs, pair_capacity,
-        topo)
-    return u[0], v[0], dropped, granted[0]
-
-
 def _derived_pair_capacity(cfg: PBAConfig, table: FactionTable) -> int:
     """The capacity every generator path uses for (cfg, table) — shared so
     host/sharded/stream runs of the same config agree on the budget."""
@@ -473,104 +458,70 @@ def _derived_pair_capacity(cfg: PBAConfig, table: FactionTable) -> int:
         exchange_rounds=cfg.exchange_rounds)
 
 
-def generate_pba(cfg: PBAConfig, table: FactionTable,
-                 mesh: Optional[Mesh] = None, axis_name: str = "proc",
-                 topology: Optional[Topology] = None
-                 ) -> tuple[EdgeList, GenStats]:
-    """Generate a PBA graph with one processor per device of ``topology``.
+def _pba_program(cfg: PBAConfig, num_procs: int, pair_capacity: int,
+                 topo: Topology):
+    """The in-memory PBA program over ``topo``: jitted
+    ``fn(procs, s) -> (u, v, dropped, rounds)``.
 
-    With mesh=None and topology=None, runs the P-processor program on a
-    flat mesh over P real devices — P == table.num_procs must equal the
-    topology's device count. ``Topology.pods(r, c)`` routes the two
-    exchanges hierarchically (bit-identical output). For P logical
-    processors on 1 device (testing), use :func:`generate_pba_host`.
+    Each device runs its block of lp = P / D logical processors through
+    :func:`pba_logical_block`. ``procs`` (D, lp, max_s) and ``s`` (D, lp)
+    are the faction table in the blocked layout (:func:`_pba_inputs`);
+    ``u``, ``v`` come back as (D, lp, E) and ``dropped``, ``rounds`` as
+    (D,). On a device topology the body runs under shard_map; on
+    ``Topology.host()`` it is the same body under plain jit, with D = 1
+    and the exchanges reduced to local transposes. The executor, the
+    compile-only harness (``repro.launch.bench.compile_sharded_pba``), the
+    auditor and flowcheck all build the program here.
     """
-    validate_table(table)
-    num_procs = table.num_procs
-    topology, mesh = topology_lib.resolve(topology, mesh, axis_name,
-                                          default_devices=num_procs)
-    if topology.num_devices != num_procs:
-        raise ValueError(
-            f"generate_pba runs 1 proc per device: table has {num_procs} "
-            f"procs but topology {topology.label} has "
-            f"{topology.num_devices} devices; use generate_pba_sharded "
-            "for P = lp * D")
-    pair_capacity = _derived_pair_capacity(cfg, table)
-    spec = topology.spec_axes
-
-    procs = jnp.asarray(table.procs)
-    s = jnp.asarray(table.s)
+    lp = topo.lp(num_procs)
 
     def body(procs_blk, s_blk):
-        ranks = blocking.logical_ranks(1, topology)
-        u, v, dropped, granted, rounds = pba_logical_block(
-            ranks, procs_blk, s_blk, cfg, num_procs, pair_capacity,
-            topology)
-        return u, v, dropped[None], granted, rounds[None]
-
-    u, v, dropped, granted, rounds = jax.jit(
-        spmd.shard_map(
-            body, mesh=mesh,
-            in_specs=(P(spec, None), P(spec)),
-            out_specs=(P(spec, None), P(spec, None), P(spec), P(spec),
-                       P(spec)),
-            check_vma=False,
-        )
-    )(procs, s)
-
-    n = num_procs * cfg.vertices_per_proc
-    edges = EdgeList(src=u, dst=v, num_vertices=n)
-    requested = num_procs * cfg.edges_per_proc
-    dropped_n = int(dropped[0])
-    stats = GenStats(requested_edges=requested,
-                     emitted_edges=requested - dropped_n,
-                     dropped_edges=dropped_n, num_vertices=n,
-                     exchange_rounds=int(rounds[0]),
-                     pair_capacity=pair_capacity)
-    return edges, stats
-
-
-def generate_pba_sharded(cfg: PBAConfig, table: FactionTable,
-                         mesh: Optional[Mesh] = None,
-                         axis_name: str = "proc",
-                         topology: Optional[Topology] = None
-                         ) -> tuple[EdgeList, GenStats]:
-    """P *logical* processors sharded over a device topology (P = lp·D).
-
-    The paper ran 1000 MPI ranks; a pod has 256 chips — production runs
-    several logical processors per chip. Each device vmaps its local block
-    of logical procs; the two exchanges become device-level distributed
-    transposes of the (local, P)-blocked counts/endpoint tensors — one flat
-    all_to_all on a 1-D topology, the hierarchical two-hop
-    intra-pod/cross-pod exchange on ``Topology.pods(r, c)``. Bit-identical
-    to generate_pba_host for the same table across every topology (tested).
-    """
-    validate_table(table)
-    num_procs = table.num_procs
-    topology, mesh = topology_lib.resolve(topology, mesh, axis_name)
-    d = topology.num_devices
-    lp = topology.lp(num_procs)  # logical procs per device
-    pair_capacity = _derived_pair_capacity(cfg, table)
-    spec = topology.spec_axes
-
-    procs = jnp.asarray(table.procs).reshape(d, lp, table.max_s)
-    s = jnp.asarray(table.s).reshape(d, lp)
-
-    def body(procs_blk, s_blk):
-        ranks = blocking.logical_ranks(lp, topology)
+        ranks = blocking.logical_ranks(lp, topo)
         u, v, dropped, _, rounds = pba_logical_block(
             ranks, procs_blk[0], s_blk[0], cfg, num_procs, pair_capacity,
-            topology)
+            topo)
         return u[None], v[None], dropped[None], rounds[None]
 
-    u, v, dropped, rounds = jax.jit(
-        spmd.shard_map(body, mesh=mesh,
-                       in_specs=(P(spec, None, None), P(spec, None)),
-                       out_specs=(P(spec, None, None),
-                                  P(spec, None, None), P(spec),
-                                  P(spec)),
-                       check_vma=False)
-    )(procs, s)
+    if topo.is_host:
+        return jax.jit(body)
+    spec = topo.spec_axes
+    return jax.jit(spmd.shard_map(
+        body, mesh=topo.build_mesh(),
+        in_specs=(P(spec, None, None), P(spec, None)),
+        out_specs=(P(spec, None, None), P(spec, None, None), P(spec),
+                   P(spec)),
+        check_vma=False))
+
+
+def _pba_inputs(table: FactionTable, topo: Topology):
+    """The faction table in the blocked layout: (D, lp, max_s) rows and
+    (D, lp) sizes."""
+    d, lp = topo.num_devices, topo.lp(table.num_procs)
+    return (jnp.asarray(table.procs).reshape(d, lp, table.max_s),
+            jnp.asarray(table.s).reshape(d, lp))
+
+
+def generate_pba(cfg: PBAConfig, table: FactionTable, topology: Topology
+                 ) -> tuple[EdgeList, GenStats]:
+    """Generate a PBA graph: P = lp * D logical processors over ``topology``.
+
+    The one in-memory executor. The paper ran 1000 MPI ranks; a pod has
+    256 chips, so each device vmaps its block of logical processors and
+    the two exchanges become distributed transposes of the blocked
+    counts and endpoint tensors: one flat all_to_all on a 1-D topology,
+    the two-hop intra-pod/cross-pod exchange on ``Topology.pods(r, c)``,
+    local transposes on ``Topology.host()``. Every topology gives the same
+    graph bit for bit (tested). The edges keep the program's (D, lp, E)
+    layout. When validating *across backends* with ``pair_capacity=None``,
+    pin the budget from ``GenStats.pair_capacity`` (the memory-aware
+    default probes per-backend device memory — see
+    :func:`default_pair_capacity`).
+    """
+    validate_table(table)
+    num_procs = table.num_procs
+    pair_capacity = _derived_pair_capacity(cfg, table)
+    program = _pba_program(cfg, num_procs, pair_capacity, topology)
+    u, v, dropped, rounds = program(*_pba_inputs(table, topology))
 
     n = num_procs * cfg.vertices_per_proc
     requested = num_procs * cfg.edges_per_proc
@@ -580,52 +531,6 @@ def generate_pba_sharded(cfg: PBAConfig, table: FactionTable,
                      emitted_edges=requested - dropped_n,
                      dropped_edges=dropped_n, num_vertices=n,
                      exchange_rounds=int(rounds[0]),
-                     pair_capacity=pair_capacity))
-
-
-def generate_pba_host(cfg: PBAConfig, table: FactionTable,
-                      topology: Optional[Topology] = None
-                      ) -> tuple[EdgeList, GenStats]:
-    """Run the P-logical-processor PBA program on a single device via vmap.
-
-    Exchanges become transposes of the vmapped batch — bit-identical logical
-    semantics to the distributed run (tested), handy for CPU validation of
-    large P. When validating *across backends* with ``pair_capacity=None``,
-    pin the budget from the distributed run's ``GenStats.pair_capacity``
-    (the memory-aware default probes per-backend device memory — see
-    :func:`default_pair_capacity`). ``topology``, if given, must be
-    ``Topology.host()`` — device topologies belong to
-    :func:`generate_pba_sharded`.
-    """
-    validate_table(table)
-    if topology is not None and not topology.is_host:
-        raise ValueError(
-            f"generate_pba_host runs the host topology; pass "
-            f"{topology.label} to generate_pba_sharded instead")
-    topo = Topology.host()
-    num_procs = table.num_procs
-    pair_capacity = _derived_pair_capacity(cfg, table)
-    procs = jnp.asarray(table.procs)
-    s = jnp.asarray(table.s)
-    ranks = jnp.arange(num_procs, dtype=jnp.int32)
-
-    @jax.jit
-    def run(procs, s, ranks):
-        # lp == P on one "device": the exchanges degenerate to local
-        # transposes under the same blocked contract as the sharded path.
-        u, v, dropped, _, rounds = pba_logical_block(
-            ranks, procs, s, cfg, num_procs, pair_capacity, topo)
-        return u, v, dropped, rounds
-
-    u, v, dropped, rounds = run(procs, s, ranks)
-    n = num_procs * cfg.vertices_per_proc
-    requested = num_procs * cfg.edges_per_proc
-    dropped_n = int(dropped)
-    return (EdgeList(src=u, dst=v, num_vertices=n),
-            GenStats(requested_edges=requested,
-                     emitted_edges=requested - dropped_n,
-                     dropped_edges=dropped_n, num_vertices=n,
-                     exchange_rounds=int(rounds),
                      pair_capacity=pair_capacity))
 
 

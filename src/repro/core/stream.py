@@ -127,7 +127,7 @@ def stream_urn_budget(cfg: PBAConfig, max_demand: int,
     layout), rounded to a power of two to keep the budget — and therefore
     the graph — stable under small demand perturbations of resumed specs;
     parity mode is the static device budget, bit-compatible with
-    ``generate_pba_host``.
+    ``generate_pba`` on any topology.
     """
     if auto_capacity:
         return _next_pow2(max(max_demand, 1))
@@ -216,7 +216,7 @@ class PBAStream:
     auto_capacity=False every pool is drawn at
     ``cfg.total_capacity_factor * E`` exactly as on-device generation
     draws it, and blocks concatenate to the bit-identical edge multiset of
-    ``generate_pba_host`` with the same streaming config.
+    ``generate_pba`` with the same streaming config.
     """
 
     def __init__(self, cfg: PBAConfig, table: FactionTable,
@@ -266,7 +266,7 @@ class PBAStream:
         # budget a pool is *drawn at* is part of the graph's identity:
         # every stream draws at the one uniform ``stream_urn_budget`` (and
         # parity mode's budget is exactly the static device budget, so
-        # blocks reproduce ``generate_pba_host`` slot for slot). The rows
+        # blocks reproduce ``generate_pba`` slot for slot). The rows
         # are trimmed to each processor's own demand after the draw, so
         # resident host memory stays O(edges).
         pool_fn = jax.jit(lambda r: _phase2_pool(r, cfg_, self.urn_budget))
@@ -422,7 +422,7 @@ class PBAShardedStream:
     (:func:`stream_round_shape`), draw pools at the same uniform
     :func:`stream_urn_budget`, and address the same slots — so manifests
     written by either driver resume under the other, and parity mode
-    (``auto_capacity=False``) reproduces ``generate_pba_host``'s edge
+    (``auto_capacity=False``) reproduces ``generate_pba``'s edge
     multiset exactly like the host stream does.
 
     ``dispatch_block(i)`` / ``gather_block(handle)`` split each block into

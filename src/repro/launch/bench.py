@@ -11,41 +11,21 @@ can pin the streamed path's collective volume too.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
-from repro.core.pba import pba_logical_block
-from repro.runtime import blocking, spmd
+from repro.core.pba import _pba_inputs, _pba_program
 
 
 def compile_sharded_pba(pl):
-    """(jitted_fn, example_args) for a sharded-execution PBA plan.
+    """(jitted_fn, example_args) for an in-memory PBA plan: the program
+    ``api.generate`` runs (:func:`repro.core.pba._pba_program`).
 
     ``fn.lower(*args).compile()`` yields the compiled program; calling
     ``fn(*args)`` runs it.
     """
-    cfg, table, topo = pl.config, pl.table, pl.topology
-    num_procs, lp, d = pl.num_procs, pl.lp, topo.num_devices
-    mesh = topo.build_mesh()
-    spec = topo.spec_axes
-
-    def body(procs_blk, s_blk):
-        ranks = blocking.logical_ranks(lp, topo)
-        u, v, dropped, _, rounds = pba_logical_block(
-            ranks, procs_blk[0], s_blk[0], cfg, num_procs,
-            pl.pair_capacity, topo)
-        return u[None], v[None], dropped[None], rounds[None]
-
-    fn = jax.jit(spmd.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(spec, None, None), P(spec, None)),
-        out_specs=(P(spec, None, None), P(spec, None, None), P(spec),
-                   P(spec)),
-        check_vma=False))
-    procs = jnp.asarray(table.procs).reshape(d, lp, table.max_s)
-    s = jnp.asarray(table.s).reshape(d, lp)
-    return fn, (procs, s)
+    fn = _pba_program(pl.config, pl.num_procs, pl.pair_capacity,
+                      pl.topology)
+    return fn, _pba_inputs(pl.table, pl.topology)
 
 
 def compile_sharded_stream_setup(pl):
@@ -58,12 +38,8 @@ def compile_sharded_stream_setup(pl):
     """
     from repro.core.stream import _sharded_setup_fn
 
-    cfg, table, topo = pl.config, pl.table, pl.topology
-    lp, d = pl.lp, topo.num_devices
-    setup = _sharded_setup_fn(cfg, pl.num_procs, topo)
-    procs = jnp.asarray(table.procs).reshape(d, lp, table.max_s)
-    s = jnp.asarray(table.s).reshape(d, lp)
-    return setup, (procs, s)
+    setup = _sharded_setup_fn(pl.config, pl.num_procs, pl.topology)
+    return setup, _pba_inputs(pl.table, pl.topology)
 
 
 def compile_sharded_stream_round(pl):

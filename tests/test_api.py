@@ -13,7 +13,7 @@ import pytest
 from repro import api
 from repro.api import GraphSpec
 from repro.core import FactionSpec, hub_factions, make_factions
-from repro.core.pba import PBAConfig, generate_pba_host
+from repro.core.pba import PBAConfig, generate_pba
 from repro.core.pk import PKConfig, generate_pk_host, star_clique_seed
 from repro.core.storage import read_shards
 from repro.core.stream import PBAStream, PKStream, stream_to_shards
@@ -52,10 +52,11 @@ def test_pba_host_parity():
     spec = PBA_SPEC.replace(execution="host")
     res = api.generate(spec)
     table = make_factions(8, FactionSpec(4, 2, 4, seed=2))
-    e_h, st_h = generate_pba_host(_legacy_pba_cfg(spec), table)
+    e_h, st_h = generate_pba(_legacy_pba_cfg(spec), table, Topology.host())
     _assert_bit_equal(res.edges, e_h)
     assert res.stats == st_h
-    assert res.plan.executor == "generate_pba_host"
+    assert res.plan.executor == "generate_pba"
+    assert res.plan.execution == "host"
 
 
 def test_pba_host_parity_streamed_exchange():
@@ -63,7 +64,8 @@ def test_pba_host_parity_streamed_exchange():
                             pair_capacity=16, exchange_rounds=4,
                             total_capacity_factor=8)
     res = api.generate(spec)
-    e_h, st_h = generate_pba_host(_legacy_pba_cfg(spec), hub_factions(8))
+    e_h, st_h = generate_pba(_legacy_pba_cfg(spec), hub_factions(8),
+                             Topology.host())
     _assert_bit_equal(res.edges, e_h)
     assert res.stats == st_h
     assert res.stats.dropped_edges == 0 and res.stats.exchange_rounds > 1
@@ -142,16 +144,15 @@ def test_non_streamed_shard_sink(tmp_path):
 # --- parity: sharded executors on 8 forced host devices ----------------------
 
 def test_sharded_parity_matrix_8dev():
-    """api.generate == generate_pba / generate_pba_sharded / generate_pk on
-    flat and pods topologies, single-shot and streamed exchange."""
+    """api.generate == generate_pba / generate_pk on flat and pods
+    topologies, single-shot and streamed exchange."""
     run_with_devices("""
         import dataclasses
         import numpy as np
         from repro import api
         from repro.api import GraphSpec
         from repro.core import FactionSpec, make_factions
-        from repro.core.pba import (PBAConfig, generate_pba,
-                                    generate_pba_sharded)
+        from repro.core.pba import PBAConfig, generate_pba
         from repro.core.pk import PKConfig, generate_pk, star_clique_seed
         from repro.runtime import Topology
 
@@ -173,16 +174,14 @@ def test_sharded_parity_matrix_8dev():
                 res = api.generate(spec.replace(execution="sharded",
                                                 topology=topo))
                 t = topo or Topology.flat(8)
-                e_1, st_1 = generate_pba(cfg, table, topology=t)
-                e_s, st_s = generate_pba_sharded(cfg, table, topology=t)
-                for ref, st in ((e_1, st_1), (e_s, st_s)):
-                    np.testing.assert_array_equal(
-                        np.asarray(res.edges.src).reshape(-1),
-                        np.asarray(ref.src).reshape(-1), err_msg=t.label)
-                    np.testing.assert_array_equal(
-                        np.asarray(res.edges.dst).reshape(-1),
-                        np.asarray(ref.dst).reshape(-1), err_msg=t.label)
-                    assert res.stats.dropped_edges == st.dropped_edges
+                ref, st = generate_pba(cfg, table, topology=t)
+                np.testing.assert_array_equal(
+                    np.asarray(res.edges.src).reshape(-1),
+                    np.asarray(ref.src).reshape(-1), err_msg=t.label)
+                np.testing.assert_array_equal(
+                    np.asarray(res.edges.dst).reshape(-1),
+                    np.asarray(ref.dst).reshape(-1), err_msg=t.label)
+                assert res.stats.dropped_edges == st.dropped_edges
                 assert res.plan.lp == 1 and res.plan.num_procs == 8
 
         # lp > 1: 16 logical procs over 8 devices
@@ -193,7 +192,7 @@ def test_sharded_parity_matrix_8dev():
                            execution="sharded")
         res16 = api.generate(spec16)
         cfg16 = PBAConfig(vertices_per_proc=50, edges_per_vertex=3, seed=5)
-        e_16, _ = generate_pba_sharded(cfg16, table16)
+        e_16, _ = generate_pba(cfg16, table16, topology=Topology.flat(8))
         np.testing.assert_array_equal(
             np.asarray(res16.edges.src).reshape(-1),
             np.asarray(e_16.src).reshape(-1))

@@ -4,12 +4,15 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import (FactionSpec, PBAConfig, block_factions,
-                        degree_counts, fit_power_law, generate_pba_host,
+                        degree_counts, fit_power_law, generate_pba,
                         make_factions, sampled_path_stats,
                         community_contrast, serial_ba_reference)
 from repro.core.pba import occurrence_rank, resolve_pointers
+from repro.runtime import Topology
 
 from helpers import run_with_devices
+
+HOST = Topology.host()
 
 
 def test_occurrence_rank():
@@ -30,7 +33,7 @@ def test_counts_conservation_and_no_drops():
     table = make_factions(8, FactionSpec(4, 2, 4, seed=2))
     cfg = PBAConfig(vertices_per_proc=500, edges_per_vertex=4,
                     interfaction_prob=0.05, seed=11)
-    edges, stats = generate_pba_host(cfg, table)
+    edges, stats = generate_pba(cfg, table, HOST)
     assert stats.requested_edges == 8 * 500 * 4
     assert stats.dropped_edges == 0
     s, d = edges.to_numpy()
@@ -46,16 +49,16 @@ def test_counts_conservation_and_no_drops():
 def test_determinism():
     table = make_factions(4, FactionSpec(2, 2, 3, seed=0))
     cfg = PBAConfig(vertices_per_proc=100, edges_per_vertex=3, seed=5)
-    e1, _ = generate_pba_host(cfg, table)
-    e2, _ = generate_pba_host(cfg, table)
+    e1, _ = generate_pba(cfg, table, HOST)
+    e2, _ = generate_pba(cfg, table, HOST)
     np.testing.assert_array_equal(np.asarray(e1.src), np.asarray(e2.src))
     np.testing.assert_array_equal(np.asarray(e1.dst), np.asarray(e2.dst))
 
 
 def test_seed_changes_graph():
     table = make_factions(4, FactionSpec(2, 2, 3, seed=0))
-    e1, _ = generate_pba_host(PBAConfig(100, 3, seed=5), table)
-    e2, _ = generate_pba_host(PBAConfig(100, 3, seed=6), table)
+    e1, _ = generate_pba(PBAConfig(100, 3, seed=5), table, HOST)
+    e2, _ = generate_pba(PBAConfig(100, 3, seed=6), table, HOST)
     assert (np.asarray(e1.dst) != np.asarray(e2.dst)).any()
 
 
@@ -64,7 +67,7 @@ def test_power_law_gamma_range():
     table = make_factions(8, FactionSpec(4, 2, 4, seed=1))
     cfg = PBAConfig(vertices_per_proc=4000, edges_per_vertex=4,
                     interfaction_prob=0.05, seed=7)
-    edges, _ = generate_pba_host(cfg, table)
+    edges, _ = generate_pba(cfg, table, HOST)
     deg = np.asarray(degree_counts(edges))
     fit = fit_power_law(deg, kmin=5)
     assert 2.0 < fit.gamma_mle < 3.6, fit
@@ -75,7 +78,7 @@ def test_small_world():
     # Paper Table 2: short avg path length, small diameter.
     table = make_factions(8, FactionSpec(4, 2, 4, seed=1))
     cfg = PBAConfig(vertices_per_proc=2000, edges_per_vertex=4, seed=7)
-    edges, _ = generate_pba_host(cfg, table)
+    edges, _ = generate_pba(cfg, table, HOST)
     ps = sampled_path_stats(edges, num_sources=8)
     assert ps.avg_path_length < 8.0
     assert ps.diameter_estimate <= 16
@@ -87,17 +90,17 @@ def test_faction_structure_creates_communities():
     table = block_factions(8, 2)
     cfg = PBAConfig(vertices_per_proc=1000, edges_per_vertex=4,
                     interfaction_prob=0.02, seed=3)
-    edges, _ = generate_pba_host(cfg, table)
+    edges, _ = generate_pba(cfg, table, HOST)
     contrast = community_contrast(edges, num_blocks=4)
     assert contrast > 2.0, contrast
 
 
 def test_interfaction_prob_spreads_edges():
     table = block_factions(8, 2)
-    lo, _ = generate_pba_host(
-        PBAConfig(500, 4, interfaction_prob=0.0, seed=3), table)
-    hi, _ = generate_pba_host(
-        PBAConfig(500, 4, interfaction_prob=0.5, seed=3), table)
+    lo, _ = generate_pba(
+        PBAConfig(500, 4, interfaction_prob=0.0, seed=3), table, HOST)
+    hi, _ = generate_pba(
+        PBAConfig(500, 4, interfaction_prob=0.5, seed=3), table, HOST)
     assert community_contrast(hi, 4) < community_contrast(lo, 4)
 
 
@@ -105,7 +108,7 @@ def test_capacity_overflow_is_counted_not_crashed():
     table = make_factions(4, FactionSpec(2, 2, 2, seed=0))
     cfg = PBAConfig(vertices_per_proc=500, edges_per_vertex=4,
                     pair_capacity=16, seed=1)  # absurdly small on purpose
-    edges, stats = generate_pba_host(cfg, table)
+    edges, stats = generate_pba(cfg, table, HOST)
     assert stats.dropped_edges > 0
     assert stats.emitted_edges + stats.dropped_edges == stats.requested_edges
     s, d = edges.to_numpy()
@@ -124,7 +127,7 @@ def test_pba_vs_serial_ba_statistics():
     table = make_factions(1, FactionSpec(1, 1, 1, seed=0))
     cfg = PBAConfig(vertices_per_proc=4000, edges_per_vertex=4, seed=2,
                     interfaction_prob=0.0)
-    e_pba, _ = generate_pba_host(cfg, table)
+    e_pba, _ = generate_pba(cfg, table, HOST)
     e_ser = serial_ba_reference(4000, 4, seed=2)
     d_pba = np.sort(np.asarray(degree_counts(e_pba)))[::-1]
     d_ser = np.sort(np.asarray(degree_counts(e_ser)))[::-1]
@@ -137,13 +140,16 @@ def test_distributed_matches_host_8dev():
     run_with_devices("""
         import numpy as np
         from repro.core import *
+        from repro.runtime import Topology
         table = make_factions(8, FactionSpec(4, 2, 4, seed=1))
         cfg = PBAConfig(vertices_per_proc=300, edges_per_vertex=3,
                         interfaction_prob=0.05, seed=7)
-        e_d, st_d = generate_pba(cfg, table)
-        e_h, st_h = generate_pba_host(cfg, table)
-        np.testing.assert_array_equal(np.asarray(e_d.src), np.asarray(e_h.src))
-        np.testing.assert_array_equal(np.asarray(e_d.dst), np.asarray(e_h.dst))
+        e_d, st_d = generate_pba(cfg, table, Topology.flat(8))
+        e_h, st_h = generate_pba(cfg, table, Topology.host())
+        np.testing.assert_array_equal(np.asarray(e_d.src).reshape(-1),
+                                      np.asarray(e_h.src).reshape(-1))
+        np.testing.assert_array_equal(np.asarray(e_d.dst).reshape(-1),
+                                      np.asarray(e_h.dst).reshape(-1))
         assert st_d.dropped_edges == st_h.dropped_edges
         print("OK")
     """, 8)
@@ -155,12 +161,13 @@ def test_logical_procs_sharded_matches_host_4dev():
     run_with_devices("""
         import numpy as np
         from repro.core import (make_factions, FactionSpec, PBAConfig,
-                                generate_pba_host, generate_pba_sharded)
+                                generate_pba)
+        from repro.runtime import Topology
         table = make_factions(16, FactionSpec(8, 2, 6, seed=2))
         cfg = PBAConfig(vertices_per_proc=200, edges_per_vertex=3,
                         interfaction_prob=0.05, seed=9)
-        e_s, st_s = generate_pba_sharded(cfg, table)
-        e_h, st_h = generate_pba_host(cfg, table)
+        e_s, st_s = generate_pba(cfg, table, Topology.flat(4))
+        e_h, st_h = generate_pba(cfg, table, Topology.host())
         np.testing.assert_array_equal(np.asarray(e_s.src).reshape(-1),
                                       np.asarray(e_h.src).reshape(-1))
         np.testing.assert_array_equal(np.asarray(e_s.dst).reshape(-1),

@@ -7,14 +7,14 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (FactionSpec, PBAConfig, PKConfig, degree_counts,
-                        generate_pba_host, generate_pk_host, make_factions,
+                        generate_pba, generate_pk_host, make_factions,
                         star_clique_seed, dense_power_seed, pk_sizes)
 from repro.core import storage
 from repro.core.pba import occurrence_rank
 from repro.core.pk import decompose_base
 from repro.core.stream import PBAStream
 from repro.kernels import ref
-from repro.runtime import streaming
+from repro.runtime import Topology, streaming
 
 SETTINGS = settings(max_examples=25, deadline=None)
 
@@ -47,7 +47,7 @@ def test_pba_degree_sum_invariant(num_procs, k, seed):
     table = make_factions(num_procs,
                           FactionSpec(2, 1, max(num_procs // 2, 1), seed=seed))
     cfg = PBAConfig(vertices_per_proc=64, edges_per_vertex=k, seed=seed)
-    edges, stats = generate_pba_host(cfg, table)
+    edges, stats = generate_pba(cfg, table, Topology.host())
     deg = np.asarray(degree_counts(edges))
     # sum of degrees == 2 * emitted edges (undirected view)
     assert deg.sum() == 2 * stats.emitted_edges

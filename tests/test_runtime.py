@@ -311,11 +311,11 @@ def test_map_logical_and_ranks_host():
 def test_pba_sharded_parity_one_device():
     """d=1 sharded run (lp == P) must equal the host path bit-for-bit."""
     from repro.core import FactionSpec, PBAConfig, make_factions
-    from repro.core.pba import generate_pba_host, generate_pba_sharded
+    from repro.core.pba import generate_pba
     table = make_factions(4, FactionSpec(2, 2, 3, seed=1))
     cfg = PBAConfig(vertices_per_proc=50, edges_per_vertex=3, seed=3)
-    e_s, st_s = generate_pba_sharded(cfg, table, mesh=spmd.make_proc_mesh(1))
-    e_h, st_h = generate_pba_host(cfg, table)
+    e_s, st_s = generate_pba(cfg, table, topology=Topology.flat(1))
+    e_h, st_h = generate_pba(cfg, table, topology=HOST)
     np.testing.assert_array_equal(np.asarray(e_s.src).reshape(-1),
                                   np.asarray(e_h.src).reshape(-1))
     np.testing.assert_array_equal(np.asarray(e_s.dst).reshape(-1),
@@ -384,29 +384,27 @@ def test_transpose_hierarchical_matches_host(rows, cols):
 
 
 def test_pba_parity_matrix_8dev():
-    """flat 1x8, pods 2x4 / 4x2, and host: all bit-identical (single-shot),
-    for both generate_pba (1 proc/device) and generate_pba_sharded."""
+    """flat 1x8, pods 2x4 / 4x2, flat 1x2 (lp=4) and host: all
+    bit-identical (single-shot)."""
     run_with_devices("""
         import numpy as np
         from repro.core import FactionSpec, PBAConfig, make_factions
-        from repro.core.pba import (generate_pba, generate_pba_host,
-                                    generate_pba_sharded)
+        from repro.core.pba import generate_pba
         from repro.runtime import Topology
         table = make_factions(8, FactionSpec(4, 2, 4, seed=2))
         cfg = PBAConfig(vertices_per_proc=100, edges_per_vertex=3, seed=5)
-        e_h, st_h = generate_pba_host(cfg, table)
+        e_h, st_h = generate_pba(cfg, table, topology=Topology.host())
         rs = np.asarray(e_h.src).reshape(-1)
         rd = np.asarray(e_h.dst).reshape(-1)
         for topo in (Topology.flat(8), Topology.pods(2, 4),
-                     Topology.pods(4, 2)):
-            for gen in (generate_pba_sharded, generate_pba):
-                e, st = gen(cfg, table, topology=topo)
-                np.testing.assert_array_equal(
-                    np.asarray(e.src).reshape(-1), rs, err_msg=topo.label)
-                np.testing.assert_array_equal(
-                    np.asarray(e.dst).reshape(-1), rd, err_msg=topo.label)
-                assert st.dropped_edges == st_h.dropped_edges
-                assert st.pair_capacity == st_h.pair_capacity > 0
+                     Topology.pods(4, 2), Topology.flat(2)):
+            e, st = generate_pba(cfg, table, topology=topo)
+            np.testing.assert_array_equal(
+                np.asarray(e.src).reshape(-1), rs, err_msg=topo.label)
+            np.testing.assert_array_equal(
+                np.asarray(e.dst).reshape(-1), rd, err_msg=topo.label)
+            assert st.dropped_edges == st_h.dropped_edges
+            assert st.pair_capacity == st_h.pair_capacity > 0
         print("OK")
     """, 8)
 
@@ -415,13 +413,13 @@ def test_pba_sharded_parity_2dev():
     """lp=4 logical procs per device through map_logical + the transposes."""
     run_with_devices("""
         import numpy as np
-        from repro.core import (FactionSpec, PBAConfig, make_factions,
-                                generate_pba_host)
-        from repro.core.pba import generate_pba_sharded
+        from repro.core import FactionSpec, PBAConfig, make_factions
+        from repro.core.pba import generate_pba
+        from repro.runtime import Topology
         table = make_factions(8, FactionSpec(4, 2, 4, seed=2))
         cfg = PBAConfig(vertices_per_proc=100, edges_per_vertex=3, seed=5)
-        e_s, st_s = generate_pba_sharded(cfg, table)
-        e_h, st_h = generate_pba_host(cfg, table)
+        e_s, st_s = generate_pba(cfg, table, topology=Topology.flat(2))
+        e_h, st_h = generate_pba(cfg, table, topology=Topology.host())
         np.testing.assert_array_equal(np.asarray(e_s.src).reshape(-1),
                                       np.asarray(e_h.src).reshape(-1))
         np.testing.assert_array_equal(np.asarray(e_s.dst).reshape(-1),

@@ -7,16 +7,18 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import (FactionSpec, PBAConfig, degree_counts,
-                        generate_pba_host, make_factions)
+                        generate_pba, make_factions)
 from repro.core.graph import EdgeList
 from repro.core.storage import iter_shards, read_shards, write_shards
+from repro.runtime import Topology
 
 from helpers import run_with_devices
 
 
 def _graph():
     table = make_factions(4, FactionSpec(2, 2, 3, seed=0))
-    return generate_pba_host(PBAConfig(500, 4, seed=3), table)[0]
+    return generate_pba(PBAConfig(500, 4, seed=3), table,
+                        Topology.host())[0]
 
 
 def test_write_read_roundtrip(tmp_path):
@@ -138,9 +140,10 @@ def test_degree_counts_sharded_matches_host_4dev():
         from repro.core.distributed_analysis import (degree_counts_sharded,
                                                      edge_count_sharded,
                                                      max_degree_sharded)
+        from repro.runtime import Topology
         table = make_factions(4, FactionSpec(2, 2, 3, seed=1))
         cfg = PBAConfig(vertices_per_proc=400, edges_per_vertex=3, seed=5)
-        edges, stats = generate_pba(cfg, table)
+        edges, stats = generate_pba(cfg, table, Topology.flat(4))
         want = np.asarray(degree_counts(edges))
         got = np.asarray(degree_counts_sharded(edges))
         np.testing.assert_array_equal(got, want)

@@ -17,7 +17,7 @@ import pytest
 
 from repro import api
 from repro.api import GraphSpec
-from repro.core import (FactionSpec, PBAConfig, generate_pba_host,
+from repro.core import (FactionSpec, PBAConfig, generate_pba,
                         make_factions, stream_to_shards)
 from repro.core import stream as stream_lib
 from repro.core.pba import (_derived_pair_capacity, exchange_memory_cap,
@@ -95,7 +95,7 @@ def test_drivers_emit_bit_identical_blocks(streams):
 
 def test_parity_mode_edges_equal_the_heuristic_capacity_graphs():
     """auto_capacity=False: the demand-sized stream, the stream pinned at
-    the heuristic's capacity and ``generate_pba_host`` emit one edge
+    the heuristic's capacity and ``generate_pba`` on the host emit one edge
     multiset, in fewer rounds for the first."""
     n = 64 * CFG.vertices_per_proc
     sized = PBAStream(CFG, TABLE, auto_capacity=False)
@@ -103,7 +103,7 @@ def test_parity_mode_edges_equal_the_heuristic_capacity_graphs():
         CFG, pair_capacity=_derived_pair_capacity(CFG, TABLE))
     pinned = PBAStream(pinned_cfg, TABLE, auto_capacity=False)
     assert sized.num_blocks <= R < pinned.num_blocks
-    e_h, st_h = generate_pba_host(CFG, TABLE)
+    e_h, st_h = generate_pba(CFG, TABLE, Topology.host())
     assert st_h.dropped_edges == 0 and st_h.exchange_rounds > R
     s0, d0 = e_h.to_numpy()
     want = np.sort(s0.reshape(-1).astype(np.int64) * n + d0.reshape(-1))

@@ -20,15 +20,16 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import (PBAConfig, PKConfig, PBAStream, PKStream, SeedGraph,
-                        degree_counts, fit_power_law, generate_pba_host,
+                        degree_counts, fit_power_law, generate_pba,
                         hub_factions, star_clique_seed, stream_to_shards)
 from repro.core.storage import read_shards
-from repro.runtime import streaming
+from repro.runtime import Topology, streaming
 
 from helpers import run_with_devices
 
 HUB_CFG = PBAConfig(vertices_per_proc=300, edges_per_vertex=4, seed=5,
                     pair_capacity=16, total_capacity_factor=8)
+HOST = Topology.host()
 
 
 # --- round/residual invariants (the streaming contract) ---------------------
@@ -59,14 +60,14 @@ def test_windows_partition_counts():
 # --- hub stress: seed drops, streaming doesn't ------------------------------
 
 def test_seed_path_drops_on_hub_table():
-    edges, stats = generate_pba_host(HUB_CFG, hub_factions(8))
+    edges, stats = generate_pba(HUB_CFG, hub_factions(8), HOST)
     assert stats.dropped_edges > 0
     assert stats.emitted_edges + stats.dropped_edges == stats.requested_edges
 
 
 def test_multiround_zero_drops_on_hub_table():
     cfg = dataclasses.replace(HUB_CFG, exchange_rounds=4)
-    edges, stats = generate_pba_host(cfg, hub_factions(8))
+    edges, stats = generate_pba(cfg, hub_factions(8), HOST)
     assert stats.dropped_edges == 0, stats
     assert stats.emitted_edges == stats.requested_edges
     assert stats.exchange_rounds > 1
@@ -91,8 +92,8 @@ def test_streaming_rounds1_bit_matches_legacy_when_no_overflow():
     cfg_legacy = PBAConfig(vertices_per_proc=200, edges_per_vertex=3, seed=3,
                            pair_capacity=2048, total_capacity_factor=8)
     cfg_stream = dataclasses.replace(cfg_legacy, exchange_rounds=1)
-    e_l, st_l = generate_pba_host(cfg_legacy, table)
-    e_s, st_s = generate_pba_host(cfg_stream, table)
+    e_l, st_l = generate_pba(cfg_legacy, table, HOST)
+    e_s, st_s = generate_pba(cfg_stream, table, HOST)
     assert st_l.dropped_edges == st_s.dropped_edges == 0
     np.testing.assert_array_equal(np.asarray(e_l.src), np.asarray(e_s.src))
     np.testing.assert_array_equal(np.asarray(e_l.dst), np.asarray(e_s.dst))
@@ -157,19 +158,18 @@ def test_streaming_sharded_matches_host(num_devices):
     bit-identical too."""
     run_with_devices(f"""
         import numpy as np
-        from repro.core import (PBAConfig, generate_pba_host,
-                                generate_pba_sharded, hub_factions)
+        from repro.core import PBAConfig, generate_pba, hub_factions
         from repro.runtime import Topology
         table = hub_factions(8)
         cfg = PBAConfig(vertices_per_proc=150, edges_per_vertex=3, seed=5,
                         pair_capacity=16, total_capacity_factor=8,
                         exchange_rounds=4)
-        e_h, st_h = generate_pba_host(cfg, table)
+        e_h, st_h = generate_pba(cfg, table, Topology.host())
         topos = [Topology.flat({num_devices})]
         if {num_devices} == 8:
             topos += [Topology.pods(2, 4), Topology.pods(4, 2)]
         for topo in topos:
-            e_s, st_s = generate_pba_sharded(cfg, table, topology=topo)
+            e_s, st_s = generate_pba(cfg, table, topology=topo)
             np.testing.assert_array_equal(np.asarray(e_s.src).reshape(-1),
                                           np.asarray(e_h.src).reshape(-1),
                                           err_msg=topo.label)
@@ -194,15 +194,15 @@ def test_gamma_mle_unbiased_vs_host_oracle():
                            total_capacity_factor=8)
     stream_cfg = dataclasses.replace(oracle_cfg, pair_capacity=64,
                                      exchange_rounds=4)
-    e_o, st_o = generate_pba_host(oracle_cfg, table)
-    e_s, st_s = generate_pba_host(stream_cfg, table)
+    e_o, st_o = generate_pba(oracle_cfg, table, HOST)
+    e_s, st_s = generate_pba(stream_cfg, table, HOST)
     assert st_o.dropped_edges == 0 and st_s.dropped_edges == 0
     g_o = fit_power_law(np.asarray(degree_counts(e_o)), kmin=5).gamma_mle
     g_s = fit_power_law(np.asarray(degree_counts(e_s)), kmin=5).gamma_mle
     assert abs(g_o - g_s) < 0.15, (g_o, g_s)
     # and the clipped seed path IS biased on this table — the bug being fixed
     clip_cfg = dataclasses.replace(oracle_cfg, pair_capacity=64)
-    e_c, st_c = generate_pba_host(clip_cfg, table)
+    e_c, st_c = generate_pba(clip_cfg, table, HOST)
     assert st_c.dropped_edges > 0
 
 
@@ -226,7 +226,7 @@ def test_pba_stream_matches_on_device_multiround():
     cfg = PBAConfig(vertices_per_proc=200, edges_per_vertex=3, seed=11,
                     pair_capacity=32, exchange_rounds=4,
                     total_capacity_factor=8)
-    e_dev, st_dev = generate_pba_host(cfg, table)
+    e_dev, st_dev = generate_pba(cfg, table, HOST)
     stream = PBAStream(cfg, table, auto_capacity=False)
     assert stream.num_blocks == st_dev.exchange_rounds
     su = np.concatenate([b.src for b in stream.iter_blocks()])

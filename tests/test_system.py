@@ -10,7 +10,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, SHAPES, applicable_shapes, get_config
 from repro.core import (FactionSpec, PBAConfig, PKConfig, degree_counts,
-                        fit_power_law, generate_pba_host, generate_pk_host,
+                        fit_power_law, generate_pk_host,
                         make_factions, star_clique_seed)
 from repro.models import build_model
 from repro.serve.engine import Engine, Request

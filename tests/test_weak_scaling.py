@@ -51,27 +51,15 @@ def test_pba_exactly_two_exchanges():
     out = run_with_devices("""
         import re, jax, numpy as np
         from repro.core import make_factions, FactionSpec, PBAConfig
-        from repro.core.pba import pba_shard_body
-        import jax.numpy as jnp
-        from jax.sharding import Mesh, PartitionSpec as P
+        from repro.core.pba import _pba_inputs, _pba_program
+        from repro.runtime import Topology
         procs = 8
         table = make_factions(procs, FactionSpec(4, 2, 4, seed=1))
         cfg = PBAConfig(vertices_per_proc=200, edges_per_vertex=3, seed=7,
                         pair_capacity=256)
-        from repro.runtime import Topology, blocking, spmd
         topo = Topology.flat(procs)
-        mesh = topo.build_mesh()
-        def body(procs_blk, s_blk):
-            rank = blocking.device_index(topo)
-            u, v, dropped, granted = pba_shard_body(
-                rank, procs_blk[0], s_blk[0], cfg, procs, 256, topo)
-            return u[None], v[None]
-        f = jax.jit(spmd.shard_map(
-            body, mesh=mesh,
-            in_specs=(P("proc", None), P("proc")),
-            out_specs=(P("proc", None), P("proc", None)), check_vma=False))
-        hlo = f.lower(jnp.asarray(table.procs),
-                      jnp.asarray(table.s)).compile().as_text()
+        f = _pba_program(cfg, procs, 256, topo)
+        hlo = f.lower(*_pba_inputs(table, topo)).compile().as_text()
         n_a2a = len(re.findall(r" all-to-all\\(", hlo))
         assert n_a2a == 2, f"expected exactly 2 all_to_alls, got {n_a2a}"
         print("OK")
@@ -126,12 +114,13 @@ def test_weak_scaling_times():
             from repro.core import (make_factions, FactionSpec, PBAConfig,
                                     generate_pba, star_clique_seed, PKConfig,
                                     generate_pk)
+            from repro.runtime import Topology
             table = make_factions({procs}, FactionSpec(
                 max({procs} // 2, 1), 1, max({procs} // 2, 1), seed=1))
             cfg = PBAConfig(vertices_per_proc=20000, edges_per_vertex=4,
                             seed=7)
             t0 = time.perf_counter()
-            edges, stats = generate_pba(cfg, table)
+            edges, stats = generate_pba(cfg, table, Topology.flat({procs}))
             jax.block_until_ready(edges.src)
             t = time.perf_counter() - t0
             assert stats.emitted_edges + stats.dropped_edges == \\

@@ -148,19 +148,32 @@ def resolve_pointers(ptr: jax.Array, terminal: jax.Array,
     ``ptr`` points strictly downward (ptr[j] < j for non-terminals) and
     terminal slots are fixed points, so ``ptr <- ptr[ptr]`` doubles chain
     progress per round; expected rounds = O(log log-chain) ~ 5-8.
+
+    The carry holds terminal slot j as ``-1 - j``, so the loop's test reads
+    the carry alone and each pass is one gather. Under ``vmap`` JAX
+    evaluates a batched test twice a pass (in the cond and again in the
+    body), so a test that gathered (``terminal[p]``) cost two gathers more
+    than the step. A pointer is encoded once it has read a terminal, one
+    pass after it reached one, so the loop stops a pass later than the
+    pointers settle; after every pass the decoded array equals ``ptr``
+    doubled that many times, ``max_rounds`` cap included.
     """
+    from repro.kernels import ops as kops
+
+    j = jnp.arange(ptr.shape[-1], dtype=ptr.dtype)
+    enc = jnp.where(terminal, -1 - j, ptr)
 
     def cond(state):
-        i, p = state
-        return (i < max_rounds) & ~jnp.all(terminal[p])
+        i, e = state
+        return (i < max_rounds) & jnp.any(e >= 0)
 
     def body(state):
-        from repro.kernels import ops as kops
-        i, p = state
-        return i + 1, kops.resolve_step(p)
+        i, e = state
+        # gather clips the encoded (negative) indices; those lanes keep e.
+        return i + 1, jnp.where(e < 0, e, kops.gather(e, e))
 
-    _, out = jax.lax.while_loop(cond, body, (jnp.int32(0), ptr))
-    return out
+    _, enc = jax.lax.while_loop(cond, body, (jnp.int32(0), enc))
+    return jnp.where(enc < 0, -1 - enc, enc)
 
 
 def occurrence_rank(a: jax.Array) -> jax.Array:

@@ -10,7 +10,9 @@ The histogram, PK expansion and cfree expansion dispatch by mode
 
 PBA's gathers (pointer doubling, grant, band) and its band compaction run
 the XLA formulation in every mode: the ``edge_resolve`` and
-``band_compact`` kernels do not lower for TPU.
+``band_compact`` kernels do not lower for TPU. The doubling loop
+(``core.pba.resolve_pointers``) calls ``gather`` on pointers that encode
+their terminals; ``resolve_step`` is the plain pass the kernel mirrors.
 
 The kernel functions themselves default ``interpret=None`` and resolve the
 mode through the same probe, so direct kernel calls and these wrappers can

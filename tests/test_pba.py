@@ -1,6 +1,7 @@
 """PBA generator: two-phase attachment invariants, BA-limit statistics."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.core import (FactionSpec, PBAConfig, block_factions,
@@ -27,6 +28,107 @@ def test_resolve_pointers_chain():
     ptr = jnp.asarray([0, 1, 0, 2, 3, 4], jnp.int32)
     out = np.asarray(resolve_pointers(ptr, terminal))
     np.testing.assert_array_equal(out, [0, 1, 0, 0, 0, 0])
+
+
+def _serial_resolve(ptr, terminal):
+    """res[j] = j if terminal[j] else res[ptr[j]], in increasing j."""
+    res = np.arange(len(ptr))
+    for j in range(len(ptr)):
+        if not terminal[j]:
+            res[j] = res[ptr[j]]
+    return res
+
+
+def _doubled(ptr, passes):
+    """ptr <- ptr[ptr], ``passes`` times: a capped resolve's result."""
+    for _ in range(passes):
+        ptr = ptr[ptr]
+    return ptr
+
+
+def _urn_row(kind, m=4096, seed=7):
+    """(ptr, terminal) of one urn: terminals are fixed points and every
+    other slot points strictly downward."""
+    j = np.arange(m)
+    rng = np.random.default_rng(seed)
+    if kind == "chain":
+        return np.maximum(j - 1, 0), j == 0
+    terminal = {"prefix": j < m // 8,                        # phase-2 pool
+                "scattered": (j < 3) | (rng.random(m) < 0.05),  # phase 1
+                "terminal": np.ones(m, bool)}[kind]
+    return np.where(terminal, j, rng.integers(0, np.maximum(j, 1))), terminal
+
+
+@pytest.mark.parametrize("kinds,max_rounds", [
+    (("prefix",), 64), (("scattered",), 64), (("chain",), 64),
+    (("chain",), 1), (("chain",), 2),
+    (("chain", "prefix", "terminal", "scattered"), 64),
+], ids=["prefix", "scattered", "chain", "chain-cap1", "chain-cap2",
+        "vmap-unequal-depths"])
+def test_resolve_pointers_matches_serial_oracle(kinds, max_rounds):
+    """Resolved slots equal the serial urn walk; under a ``max_rounds``
+    cap, the pointers doubled that many times. A batch of rows whose
+    depths differ runs under vmap, as the stream programs run it."""
+    rows = [_urn_row(k) for k in kinds]
+    ptr = np.stack([p for p, _ in rows])
+    terminal = np.stack([t for _, t in rows])
+    got = np.asarray(jax.vmap(lambda p, t: resolve_pointers(p, t, max_rounds))(
+        jnp.asarray(ptr, jnp.int32), jnp.asarray(terminal)))
+    for g, p, t in zip(got, ptr, terminal):
+        want = (_serial_resolve(p, t) if max_rounds == 64
+                else _doubled(p, max_rounds))
+        np.testing.assert_array_equal(g, want)
+
+
+def test_phase2_pool_matches_serial_oracle():
+    """Three ranks' urn pools equal the serial urn walk over the same
+    draws, mapped to global vertex ids."""
+    from repro.core import rng as rng_lib
+    from repro.core.pba import _phase2_pool
+    cfg = PBAConfig(vertices_per_proc=300, edges_per_vertex=3,
+                    seed=2718281828)
+    e_local = cfg.edges_per_proc
+    pool_n = e_local + cfg.total_capacity_factor * e_local
+    pools = np.asarray(jax.vmap(lambda r: _phase2_pool(r, cfg))(
+        jnp.arange(3, dtype=jnp.int32)))
+    jj = np.arange(pool_n)
+    terminal = jj < e_local
+    for rank in range(3):
+        key = rng_lib.device_key(cfg.seed, rng_lib.STREAM_PBA_PHASE2_URN,
+                                 jnp.int32(rank))
+        r = np.asarray(rng_lib.uniform_slots(
+            key, pool_n, jnp.asarray(np.maximum(jj, 1), jnp.int32)))
+        slot = _serial_resolve(np.where(terminal, jj, r), terminal)
+        np.testing.assert_array_equal(
+            pools[rank], rank * cfg.vertices_per_proc
+            + slot // cfg.edges_per_vertex)
+
+
+def test_stream_setup_while_loops_gather_once_per_pass():
+    """Each pointer-doubling loop of the stream's setup and pool programs
+    gathers once per pass: its cond and body hold one gather between them.
+    Under vmap the lowering evaluates the cond a second time inside the
+    body, so a gather in the cond would cost two a pass."""
+    from repro.analysis.audit import iter_eqns
+    from repro.core import stream as stream_lib
+
+    def while_gathers(jaxpr):
+        return [sum(e.primitive.name == "gather"
+                    for part in ("cond_jaxpr", "body_jaxpr")
+                    for e in iter_eqns(eqn.params[part].jaxpr))
+                for eqn in iter_eqns(jaxpr) if eqn.primitive.name == "while"]
+
+    table = make_factions(4, FactionSpec(2, 2, 3, seed=0))
+    cfg = PBAConfig(vertices_per_proc=100, edges_per_vertex=3,
+                    exchange_rounds=2, seed=5)
+    topo = Topology.flat(1)
+    setup = stream_lib._sharded_setup_fn(cfg, 4, topo)
+    procs = jnp.asarray(table.procs)[None]
+    s = jnp.asarray(table.s)[None]
+    pool_fn, _ = stream_lib._sharded_grant_fns(cfg, 4, topo, 512, 16, 64)
+    for name, jaxpr in (("setup", jax.make_jaxpr(setup)(procs, s)),
+                        ("pool", jax.make_jaxpr(pool_fn)())):
+        assert while_gathers(jaxpr.jaxpr) == [1], name
 
 
 def test_counts_conservation_and_no_drops():

@@ -3,8 +3,9 @@
 
     python3 bench/control.py --workload <cell> --seeds <n> [<n> ...]
 
-For each seed: one graph through the timed path (``harness.one_graph``),
-the plain reference, and the reference's control (the same computation
+For each seed: one graph through the timed path (``harness.one_graph``;
+a shard-sink cell's read back from disk as its runs read it), the plain
+reference, and the reference's control (the same computation
 one precision lower, see ``bench/references``). Prints one JSON line per
 seed: whether the program's graph and the control's graph each differ
 from the reference (``graphs_wrong`` as the harness counts it), and the
@@ -21,7 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def readings(cell, seeds, require_chip: bool = True):
     import jax
-    from bench import harness
+    from bench import harness, shards
     from bench.edges import to_host
     if require_chip and jax.devices()[0].platform != "tpu":
         raise harness.NoChip("the control readings need the chip")
@@ -30,18 +31,24 @@ def readings(cell, seeds, require_chip: bool = True):
     from repro.runtime import spmd
     spmd.enable_compile_cache()
     ref = cell.reference()
-    for seed in seeds:
-        g, _ = harness.one_graph(api, harness.graph_spec(cell, seed),
-                                 ref.ORDERED)
-        got = to_host(g.fingerprint)
-        want = tuple(ref.reference(cell.config, seed))
-        control = tuple(ref.reference(cell.config, seed, control=True))
-        yield {"seed": seed,
-               "program_graphs_wrong": int(got != want),
-               "control_graphs_wrong": int(control != want),
-               "edges_dropped": g.dropped,
-               "edges_missing": g.requested - g.emitted,
-               "graph_s": g.seconds}
+    with shards.disk_for(cell) as disk:
+        for seed in seeds:
+            g, _ = harness.one_graph(api, harness.graph_spec(cell, seed),
+                                     ref.ORDERED,
+                                     out_dir=disk.fresh() if disk else None)
+            if disk is None:
+                got = to_host(g.fingerprint)
+            else:
+                shards.read_back(g, ref.ORDERED)
+                got = tuple(g.fingerprint)
+            want = tuple(ref.reference(cell.config, seed))
+            control = tuple(ref.reference(cell.config, seed, control=True))
+            yield {"seed": seed,
+                   "program_graphs_wrong": int(got != want),
+                   "control_graphs_wrong": int(control != want),
+                   "edges_dropped": g.dropped,
+                   "edges_missing": g.requested - g.emitted,
+                   "graph_s": g.seconds}
 
 
 def main(argv) -> int:

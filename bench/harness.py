@@ -22,8 +22,12 @@ compiles), then a closed-loop window: one caller requests whole graphs
 through the front door, ``api.generate(api.plan(spec))``, back to back,
 waits for each graph's edges in the sink, fingerprints them on the
 device and drops them, until ``--seconds`` have passed; the graph in
-flight finishes. With ``--trace 1`` the window runs under the profiler
-and the per-layer metrics are read from the trace; otherwise the
+flight finishes. A cell whose traffic sinks into shards
+(``bench/shards.py``) gives every graph a fresh directory on the
+checkout's disk, ends its window also once it holds ``MAX_GRAPHS``
+graphs, and reads every graph back from disk after the window. With
+``--trace 1`` the window runs under the profiler and the per-layer
+metrics are read from the trace; otherwise the
 end-to-end ones are reported. After the window the plain reference
 computes the fingerprint every graph must have, and ``correct`` says
 whether all of them did, in the warm-up graph's order, with no edge
@@ -48,6 +52,8 @@ import sys
 import tempfile
 import time
 from typing import Optional
+
+from bench import shards
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
@@ -172,6 +178,8 @@ class Graph:
     seconds: float
     fingerprint: object = None     # device value until the window closes
     order: object = None           # ordered fingerprint, likewise
+    out_dir: Optional[str] = None  # shard sink: the graph's directory
+    disk_bytes: int = 0            # shard sink: bytes of its shard files
 
     @property
     def whole(self) -> bool:
@@ -216,20 +224,33 @@ def graph_spec(cell: Cell, seed: int):
                      topology=Topology.flat(cell.chips))
 
 
-def one_graph(api, spec, ordered: bool) -> tuple:
+def one_graph(api, spec, ordered: bool, out_dir: Optional[str] = None
+              ) -> tuple:
     """Request one graph through the front door and wait for its edges.
-    Returns (Graph, plan); the graph's fingerprints are left in flight."""
+    Returns (Graph, plan); the graph's fingerprints are left in flight.
+    With ``out_dir`` the graph is sunk into shards there, and its clock
+    stops once every shard and the manifest are in place; its edges are
+    counted and fingerprinted when it is read back
+    (``shards.read_back``)."""
     import jax
     from bench.edges import fingerprint
+    if out_dir is not None:
+        spec = dataclasses.replace(spec, out_dir=out_dir)
     with jax.profiler.TraceAnnotation("graph"):
         t = time.perf_counter()
         with jax.profiler.TraceAnnotation("plan"):
             pl = api.plan(spec)
         with jax.profiler.TraceAnnotation("generate"):
             res = api.generate(pl)
-            jax.block_until_ready((res.edges.src, res.edges.dst))
+            if out_dir is None:
+                jax.block_until_ready((res.edges.src, res.edges.dst))
         took = time.perf_counter() - t
         st = res.stats
+        if out_dir is not None:
+            return Graph(requested=st.requested_edges, emitted=0,
+                         dropped=st.dropped_edges,
+                         rounds=st.exchange_rounds, seconds=took,
+                         out_dir=out_dir), pl
         fp = fingerprint(res.edges.src, res.edges.dst, ordered=ordered)
         g = Graph(requested=st.requested_edges, emitted=st.emitted_edges,
                   dropped=st.dropped_edges, rounds=st.exchange_rounds,
@@ -267,7 +288,16 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
     peaks = load_peaks(cell.root, dev.device_kind) if require_chip else None
     if not 0 <= seed < 2**32:
         raise BenchError(f"--seed {seed} is outside [0, 2**32)")
+    with shards.disk_for(cell) as disk:
+        return _measure(cell, seed, seconds, traced, t0, trace_dir,
+                        devices, peaks, disk)
 
+
+def _measure(cell: Cell, seed: int, seconds: float, traced: bool,
+             t0: float, trace_dir: Optional[str], devices: list,
+             peaks: Optional[dict], disk: Optional[shards.Disk]) -> dict:
+    import jax
+    dev = devices[0]
     sys.path.insert(0, os.path.join(cell.root, "src"))
     from repro import api
     from repro.runtime import spmd
@@ -291,9 +321,15 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
     try:
         t = time.perf_counter()
         spec = graph_spec(cell, seed)
-        warm, plan = one_graph(api, spec, ref.ORDERED)
-        warm.fingerprint = to_host(warm.fingerprint)
-        warm.order = to_host(warm.order)
+
+        def take():
+            return one_graph(api, spec, ref.ORDERED,
+                             out_dir=disk.fresh() if disk else None)
+
+        warm, plan = take()
+        if disk is None:
+            warm.fingerprint = to_host(warm.fingerprint)
+            warm.order = to_host(warm.order)
         setup_s = time.perf_counter() - t0
         say(f"set-up {setup_s:.3f} s: imports {t - t0:.3f} s, warm-up "
             f"graph {warm.seconds:.3f} s in {warm.rounds} exchange rounds, "
@@ -311,11 +347,13 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
         w0 = time.perf_counter()
         try:
             with jax.profiler.TraceAnnotation("window"):
-                while time.perf_counter() - w0 < seconds:
-                    graphs.append(one_graph(api, spec, ref.ORDERED)[0])
-                for g in graphs:
-                    g.fingerprint = to_host(g.fingerprint)
-                    g.order = to_host(g.order)
+                while time.perf_counter() - w0 < seconds and (
+                        disk is None or len(graphs) < shards.MAX_GRAPHS):
+                    graphs.append(take()[0])
+                if disk is None:
+                    for g in graphs:
+                        g.fingerprint = to_host(g.fingerprint)
+                        g.order = to_host(g.order)
             w1 = time.perf_counter()
         finally:
             if traced:
@@ -348,6 +386,16 @@ def execute(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
         device["busy_s"] = busy / 1e9 if busy is not None else None
         device["window_s"] = (hi - lo) / 1e9
         breakdown = tracereduce.breakdown(run.trace, lo, hi)
+
+    if disk is not None:
+        t = time.perf_counter()
+        for i, g in enumerate([warm] + graphs):
+            why = shards.read_back(g, ref.ORDERED)
+            if why:
+                say(f"graph {i} (0 is the warm-up) cannot be read back: "
+                    f"{why}")
+        say(f"{len(graphs) + 1} graphs read back from disk in "
+            f"{time.perf_counter() - t:.3f} s")
 
     metrics = {}
     for entry, reader in cell.readers(traced):

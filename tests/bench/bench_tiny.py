@@ -3,7 +3,8 @@ seconds, for the tests of tests/bench.
 
 ``make_root(dst)`` copies ``BENCHMARK.json`` and ``bench/`` under ``dst``,
 links the program's ``src/`` beside them, and shrinks each configuration's
-scale (PBA: 8 ranks x 500 vertices; R-MAT: scale 12). ``run(root, argv)``
+scale (PBA: 8 ranks x 500 vertices; R-MAT: scale 12, the shard sink's
+in 16 slabs of 2^12 edges, 16 shards as at full size). ``run(root, argv)``
 drives ``bench/harness.main`` in this process without its look for a chip
 and returns (exit code, stdout lines, stderr).
 """
@@ -27,6 +28,8 @@ from bench import harness  # noqa: E402
 PBA_PROCS = 8
 PBA_VERTICES = 500
 RMAT_SCALE = 12
+#: Slabs, so shards, per shard-sink graph: as many as at full size.
+SHARDS = 16
 
 
 def make_root(dst: str, rmat_scale: int = RMAT_SCALE) -> str:
@@ -49,6 +52,9 @@ def make_root(dst: str, rmat_scale: int = RMAT_SCALE) -> str:
         json.dump(cfg, f)
     edit_config(dst, "rmat_graph500", cfree_vertices=1 << rmat_scale,
                 cfree_edges=16 << rmat_scale)
+    edit_config(dst, "rmat_graph500_shards", cfree_vertices=1 << RMAT_SCALE,
+                cfree_edges=16 << RMAT_SCALE,
+                slab_edges=(16 << RMAT_SCALE) // SHARDS)
     return dst
 
 
